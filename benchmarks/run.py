@@ -32,6 +32,8 @@ import sys
 import time
 import traceback
 
+from repro.compile_cache import enable_compile_cache
+
 MODULES = ["turnaround", "energy", "esd_sweep", "kernel_micro",
            "serve_bench", "fleet_bench", "scenario_soak",
            "roofline_report"]
@@ -161,6 +163,7 @@ def main(argv=None) -> int:
                          "on any per-metric tolerance regression")
     args = ap.parse_args(argv)
     only = [s for s in args.only.split(",") if s]
+    enable_compile_cache()
 
     rows = []
     failures = []

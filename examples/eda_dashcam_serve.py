@@ -15,6 +15,7 @@ import time
 import numpy as np
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.config import EDAConfig
 from repro.configs.eda_vision import detector_config, pose_config
 from repro.core.runtime import EDARuntime, PAPER_DEVICES
@@ -57,6 +58,7 @@ class RealExecutor:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--pairs", type=int, default=8)
     ap.add_argument("--fps", type=int, default=6)

@@ -27,12 +27,14 @@ O(fleet), so ``--follow`` keeps up at 10k streams.
 """
 import argparse
 
+from repro.compile_cache import enable_compile_cache
 from repro.obs import FleetStatus, MetricsRegistry, SpanTracer
 from repro.simulate import get_scenario, list_scenarios
 from repro.simulate.runner import ScenarioRunner
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--list", action="store_true",
                     help="list the scenario library and exit")

@@ -12,10 +12,12 @@ reported run exactly:
 """
 import argparse
 
+from repro.compile_cache import enable_compile_cache
 from repro.simulate import get_scenario, list_scenarios, run_scenario
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--list", action="store_true",
                     help="list the scenario library and exit")

@@ -12,12 +12,14 @@ import argparse
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.config import EDAConfig
 from repro.data import DashCamSource
 from repro.streams import FleetGateway, VisionServeEngine
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--vehicles", type=int, default=12)
     ap.add_argument("--replicas", type=int, default=2)

@@ -11,6 +11,7 @@ import tempfile
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.config import ParallelConfig, get_arch
 from repro.data import lm_batches
 from repro.models import transformer as T
@@ -19,6 +20,7 @@ from repro.train import (AdamWConfig, checkpoint, init_opt_state,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="starcoder2-3b")
     ap.add_argument("--steps", type=int, default=200)

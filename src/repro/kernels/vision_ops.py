@@ -21,15 +21,34 @@ batch through HBM.  This suite fuses the ingest stage into two kernels:
 
 Fusion layout
 -------------
-Grid is ``(S,)`` — one program per stream lane; every operand block is one
-stream's data, so the whole working set (frame + reference + both outputs,
-~50 KB at 64x64x3 fp32) is VMEM-resident for the life of the program.
-Resampling is expressed as two one-hot / box-weight matmuls (``P_y @ X @
-P_x^T``) so it runs on the MXU and — for ``method="nearest"`` — is
-bit-identical to the gather in ``models.vision.downscale`` (a one-hot matmul
-adds exact zeros).  Block-SAD uses 0/1 block-membership matmuls followed by a
-division by the per-block valid-pixel count, so H, W need not divide
-``block`` (pad-and-mask semantics, matching ``ref.block_sad_ref``).
+Grid is ``(S,)`` — one program per stream lane.  The jitted wrappers view
+every ``(S, H, W, C)`` operand as ``(S, H, W*C)`` (a free row-major
+reshape) outside ``pallas_call``, so each block is one stream's ``(H, W*C)``
+plane: rows on the sublane axis, interleaved pixel-channels on the 128-lane
+axis, and the kernel bodies never reshape.  Resampling is two MXU matmuls,
+``wy @ X @ kron(wx, I_C)^T``; the column matrix carries the channel
+interleave, so channels never mix.  For ``method="nearest"`` both matrices
+are one-hot and the matmuls run at ``Precision.HIGHEST``, which makes the
+result bit-identical to the gather in ``models.vision.downscale`` (a one-hot
+matmul adds exact zeros; at default precision the MXU would round the frame
+to bf16).  Block-SAD first takes the per-pixel channel mean with three
+channel-select matmuls (the same sum-then-divide order as a ``mean`` over
+the channel axis), then sums blocks with 0/1 block-membership matmuls and
+divides by the per-block valid-pixel count, so H, W need not divide
+``block`` (pad-and-mask semantics, matching ``ref.block_sad_ref``).  The
+resample, channel-select and block matrices are built once per call in the
+wrapper and passed as whole-array operands whose block index never changes,
+so they are fetched into VMEM once for the whole grid.
+
+VMEM budget: at the serving geometry (384 px frames, 192 px model input,
+32 px gate, fp32) one ingest program holds a 1.8 MB frame block, the
+2.7 MB model column matrix and a 0.4 MB model output, each double-buffered,
+plus the matmul intermediates.  The v5e compiler accepts that at a 12 MiB
+scoped-VMEM limit and refuses it at 8 MiB, so it fits the 16 MiB default;
+larger frames need row tiling.  No scalar-per-lane value sits in a VMEM
+block, whose last two dims must tile by (8, 128): the motion score is
+written to a lane-dense ``(S, 8, 128)`` block and sliced afterwards, and
+the admit mask reaches ``scatter_admit`` through scalar prefetch (SMEM).
 
 Host/XLA split assumption
 -------------------------
@@ -38,14 +57,14 @@ The host owns stream lifecycle, backlog deques and the admission *decision*
 device owns everything O(pixels): normalize, resample, score, scatter.  The
 engine stages frames into a pinned host buffer and ships one (S, H, W, C)
 array per tick; only the (S,) score vector crosses back before the admit
-mask returns for ``scatter_admit``.  Frames are assumed to arrive at engine
-frame resolution (small, e.g. 64x64) — decode/crop from camera-native
-resolution happens upstream, so per-program VMEM stays far under budget.
+mask returns for ``scatter_admit``.  Frames arrive at engine frame
+resolution — decode/crop from camera-native resolution happens upstream —
+and a whole frame is one block, so the frame resolution is bounded by VMEM.
 
-``interpret=None`` auto-selects interpreter mode off-TPU: this container is
-CPU-only, so the tier-1 parity suite (``tests/test_vision_kernels.py``)
-executes the kernel bodies interpreted against ``ref.py`` goldens; on TPU the
-same calls compile to Mosaic.
+``interpret=None`` auto-selects interpreter mode off-TPU, so the CPU parity
+suite (``tests/test_vision_kernels.py``) executes the kernel bodies
+interpreted against ``ref.py`` goldens; on TPU the same calls compile to
+Mosaic (``tests/test_tpu_compile.py`` compiles them for a described v5e).
 """
 from __future__ import annotations
 
@@ -54,8 +73,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 METHODS = ("nearest", "box")
+
+# one (8, 128) f32 tile per lane carries its motion score
+_SCORE_TILE = (8, 128)
 
 
 def default_interpret() -> bool:
@@ -80,83 +103,107 @@ def _resample_weights(n_out: int, n_in: int, method: str) -> jax.Array:
     return w / (hi - lo).astype(jnp.float32)
 
 
+def _resample_mats(res: int, H: int, W: int, C: int, method: str):
+    """Row matrix (res, H) and interleaved column matrix (W*C, res*C) that
+    resample an (H, W*C) plane to (res, res*C)."""
+    wy = _resample_weights(res, H, method)
+    kx = jnp.kron(_resample_weights(res, W, method).T,
+                  jnp.eye(C, dtype=jnp.float32))
+    return wy, kx
+
+
 def _block_weights(n: int, block: int):
     """0/1 membership matrix (nb, n) for fixed-size blocks (last partial)
-    plus the per-block valid count (nb,) — pad-and-mask block means."""
+    plus the per-block valid count (nb, 1) — pad-and-mask block means."""
     nb = pl.cdiv(n, block)
     i = jax.lax.broadcasted_iota(jnp.int32, (nb, n), 0)
     j = jax.lax.broadcasted_iota(jnp.int32, (nb, n), 1)
     w = ((j >= i * block) & (j < (i + 1) * block)).astype(jnp.float32)
-    k = jax.lax.broadcasted_iota(jnp.int32, (nb, 1), 0)       # TPU: 2D iota
+    k = jax.lax.broadcasted_iota(jnp.int32, (nb, 1), 0)
     cnt = jnp.minimum(block, n - k * block).astype(jnp.float32)
     return w, cnt
 
 
+def _sad_mats(H: int, W: int, C: int, block: int):
+    """Operands of :func:`_sad_score` for an (H, W*C) plane: channel-select
+    matrices (C, W*C, W), row/column block membership (nbh, H) / (W, nbw)
+    and the per-block valid-pixel counts (nbh, nbw)."""
+    r = jax.lax.broadcasted_iota(jnp.int32, (C, W * C, W), 1)
+    w = jax.lax.broadcasted_iota(jnp.int32, (C, W * C, W), 2)
+    c = jax.lax.broadcasted_iota(jnp.int32, (C, W * C, W), 0)
+    sel = (r == w * C + c).astype(jnp.float32)
+    wbh, cnt_h = _block_weights(H, block)
+    wbw, cnt_w = _block_weights(W, block)
+    return sel, wbh, wbw.T, cnt_h * cnt_w.T
+
+
 def _mm(a: jax.Array, b: jax.Array) -> jax.Array:
     return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
                                preferred_element_type=jnp.float32)
 
 
-def _resample(x: jax.Array, wy: jax.Array, wx: jax.Array) -> jax.Array:
-    """(H, W, C) -> (m, n, C) via two MXU matmuls: wy @ x then wx @ x^T."""
-    H, W, C = x.shape
-    m, n = wy.shape[0], wx.shape[0]
-    t = _mm(wy, x.reshape(H, W * C))                       # (m, W*C)
-    t = t.reshape(m, W, C).swapaxes(0, 1).reshape(W, m * C)
-    t = _mm(wx, t)                                         # (n, m*C)
-    return t.reshape(n, m, C).swapaxes(0, 1)               # (m, n, C)
-
-
-def _sad_score(small: jax.Array, ref: jax.Array, block: int) -> jax.Array:
-    """Max block mean-absolute-difference of two (g, g, C) frames."""
-    g = small.shape[0]
-    d = jnp.abs(small - ref.astype(jnp.float32)).mean(axis=-1)   # (g, g)
-    wb, cnt = _block_weights(g, block)                       # cnt: (nb, 1)
-    sums = _mm(_mm(wb, d), wb.swapaxes(0, 1))
-    # wb @ d @ wb^T sums each block; divide by the valid-pixel count so a
-    # partial edge block averages only real pixels (pad-and-mask)
-    return jnp.max(sums / (cnt * cnt.swapaxes(0, 1)))
+def _sad_score(small: jax.Array, ref: jax.Array, sel, wbh, wbwT,
+               cnt) -> jax.Array:
+    """Max block mean-absolute-difference of two (H, W*C) planes, as a
+    (1, 1) value."""
+    diff = jnp.abs(small - ref.astype(jnp.float32))          # (H, W*C)
+    C = sel.shape[0]
+    # per-pixel channel mean, summed channel by channel then divided —
+    # the order of a mean over the channel axis
+    d = _mm(diff, sel[0])
+    for c in range(1, C):
+        d = d + _mm(diff, sel[c])
+    d = d / C                                                # (H, W)
+    # wbh @ d @ wbw^T sums each block; divide by the valid-pixel count so
+    # a partial edge block averages only real pixels (pad-and-mask)
+    q = _mm(_mm(wbh, d), wbwT) / cnt
+    return jnp.max(jnp.max(q, axis=1, keepdims=True), axis=0, keepdims=True)
 
 
 # ---------------------------------------------------------------------------
-# Kernels
+# Kernels (every frame ref is one stream's (H, W*C) plane)
 # ---------------------------------------------------------------------------
 
 
-def _ingest_kernel(frames_ref, refs_ref, model_out, gate_out, score_out, *,
-                   scale: float, method: str, block: int,
-                   model_res: int, gate_res: int):
+def _ingest_kernel(frames_ref, refs_ref, wym_ref, kxm_ref, wyg_ref, kxg_ref,
+                   sel_ref, wbh_ref, wbwT_ref, cnt_ref,
+                   model_out, gate_out, score_out, *, scale: float):
     x = frames_ref[0].astype(jnp.float32) * scale
-    H, W, _ = x.shape
-    model = _resample(x, _resample_weights(model_res, H, method),
-                      _resample_weights(model_res, W, method))
-    small = _resample(x, _resample_weights(gate_res, H, method),
-                      _resample_weights(gate_res, W, method))
-    model_out[0] = model
+    model_out[0] = _mm(_mm(wym_ref[...], x), kxm_ref[...])
+    small = _mm(_mm(wyg_ref[...], x), kxg_ref[...])
     gate_out[0] = small
-    score_out[0, 0] = _sad_score(small, refs_ref[0], block)
+    score = _sad_score(small, refs_ref[0], sel_ref[...], wbh_ref[...],
+                       wbwT_ref[...], cnt_ref[...])
+    score_out[0] = jnp.broadcast_to(score, _SCORE_TILE)
 
 
-def _downscale_kernel(frames_ref, out_ref, *, scale: float, method: str,
-                      res: int):
+def _downscale_kernel(frames_ref, wy_ref, kx_ref, out_ref, *, scale: float):
     x = frames_ref[0].astype(jnp.float32) * scale
-    H, W, _ = x.shape
-    out_ref[0] = _resample(x, _resample_weights(res, H, method),
-                           _resample_weights(res, W, method))
+    out_ref[0] = _mm(_mm(wy_ref[...], x), kx_ref[...])
 
 
-def _block_sad_kernel(refs_ref, frames_ref, score_out, *, block: int):
-    score_out[0, 0] = _sad_score(frames_ref[0].astype(jnp.float32),
-                                 refs_ref[0], block)
+def _block_sad_kernel(refs_ref, frames_ref, sel_ref, wbh_ref, wbwT_ref,
+                      cnt_ref, score_out):
+    score = _sad_score(frames_ref[0].astype(jnp.float32), refs_ref[0],
+                       sel_ref[...], wbh_ref[...], wbwT_ref[...],
+                       cnt_ref[...])
+    score_out[0] = jnp.broadcast_to(score, _SCORE_TILE)
 
 
 def _scatter_kernel(admit_ref, batch_ref, model_ref, refs_ref, gate_ref,
                     batch_out, refs_out):
-    take = admit_ref[0, 0] != 0
-    batch_out[0] = jnp.where(take, model_ref[0].astype(batch_out.dtype),
-                             batch_ref[0])
-    refs_out[0] = jnp.where(take, gate_ref[0].astype(refs_out.dtype),
-                            refs_ref[0])
+    take = admit_ref[pl.program_id(0)] != 0
+
+    @pl.when(take)
+    def _adopt():
+        batch_out[...] = model_ref[...].astype(batch_out.dtype)
+        refs_out[...] = gate_ref[...].astype(refs_out.dtype)
+
+    @pl.when(jnp.logical_not(take))
+    def _keep():
+        batch_out[...] = batch_ref[...]
+        refs_out[...] = refs_ref[...]
 
 
 # ---------------------------------------------------------------------------
@@ -164,9 +211,27 @@ def _scatter_kernel(admit_ref, batch_ref, model_ref, refs_ref, gate_ref,
 # ---------------------------------------------------------------------------
 
 
+def _planes(x: jax.Array) -> jax.Array:
+    """(S, H, W, C) -> (S, H, W*C): the kernels' lane-dense row layout."""
+    S, H, W, C = x.shape
+    return x.reshape(S, H, W * C)
+
+
 def _row(shape):
     """BlockSpec for one stream's row of an (S, ...) operand."""
-    return pl.BlockSpec((1,) + tuple(shape), lambda s: (s,) + (0,) * len(shape))
+    return pl.BlockSpec((1,) + tuple(shape),
+                        lambda s, *_: (s,) + (0,) * len(shape))
+
+
+def _whole(x):
+    """BlockSpec for an operand every program reads whole (same block at
+    every grid step, so it is copied into VMEM once)."""
+    return pl.BlockSpec(x.shape, lambda s, *_: (0,) * x.ndim)
+
+
+def _scores(S: int):
+    return (pl.BlockSpec((1,) + _SCORE_TILE, lambda s: (s, 0, 0)),
+            jax.ShapeDtypeStruct((S,) + _SCORE_TILE, jnp.float32))
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -175,24 +240,26 @@ def _ingest_frame_jit(frames, refs, *, model_res, gate_res, block, method,
                       interpret):
     S, H, W, C = frames.shape
     g = refs.shape[1]
-    kernel = functools.partial(
-        _ingest_kernel, scale=_norm_scale(frames.dtype), method=method,
-        block=block, model_res=model_res, gate_res=gate_res)
+    mats = (*_resample_mats(model_res, H, W, C, method),
+            *_resample_mats(gate_res, H, W, C, method),
+            *_sad_mats(g, g, C, block))
+    score_spec, score_shape = _scores(S)
     model, gate, score = pl.pallas_call(
-        kernel,
+        functools.partial(_ingest_kernel, scale=_norm_scale(frames.dtype)),
         grid=(S,),
-        in_specs=[_row((H, W, C)), _row((g, g, C))],
-        out_specs=(_row((model_res, model_res, C)),
-                   _row((gate_res, gate_res, C)),
-                   pl.BlockSpec((1, 1), lambda s: (s, 0))),
-        out_shape=(jax.ShapeDtypeStruct((S, model_res, model_res, C),
+        in_specs=[_row((H, W * C)), _row((g, g * C))]
+        + [_whole(m) for m in mats],
+        out_specs=(_row((model_res, model_res * C)),
+                   _row((gate_res, gate_res * C)), score_spec),
+        out_shape=(jax.ShapeDtypeStruct((S, model_res, model_res * C),
                                         jnp.float32),
-                   jax.ShapeDtypeStruct((S, gate_res, gate_res, C),
+                   jax.ShapeDtypeStruct((S, gate_res, gate_res * C),
                                         jnp.float32),
-                   jax.ShapeDtypeStruct((S, 1), jnp.float32)),
+                   score_shape),
         interpret=interpret,
-    )(frames, refs)
-    return model, gate, score[:, 0]
+    )(_planes(frames), _planes(refs), *mats)
+    return (model.reshape(S, model_res, model_res, C),
+            gate.reshape(S, gate_res, gate_res, C), score[:, 0, 0])
 
 
 def ingest_frame(frames: jax.Array, refs: jax.Array, *, model_res: int,
@@ -213,17 +280,17 @@ def ingest_frame(frames: jax.Array, refs: jax.Array, *, model_res: int,
 @functools.partial(jax.jit, static_argnames=("res", "method", "interpret"))
 def _downscale_jit(frames, *, res, method, interpret):
     S, H, W, C = frames.shape
-    kernel = functools.partial(_downscale_kernel,
-                               scale=_norm_scale(frames.dtype),
-                               method=method, res=res)
-    return pl.pallas_call(
-        kernel,
+    mats = _resample_mats(res, H, W, C, method)
+    out = pl.pallas_call(
+        functools.partial(_downscale_kernel,
+                          scale=_norm_scale(frames.dtype)),
         grid=(S,),
-        in_specs=[_row((H, W, C))],
-        out_specs=_row((res, res, C)),
-        out_shape=jax.ShapeDtypeStruct((S, res, res, C), jnp.float32),
+        in_specs=[_row((H, W * C))] + [_whole(m) for m in mats],
+        out_specs=_row((res, res * C)),
+        out_shape=jax.ShapeDtypeStruct((S, res, res * C), jnp.float32),
         interpret=interpret,
-    )(frames)
+    )(_planes(frames), *mats)
+    return out.reshape(S, res, res, C)
 
 
 def downscale(frames: jax.Array, res: int, *, method: str = "nearest",
@@ -238,16 +305,18 @@ def downscale(frames: jax.Array, res: int, *, method: str = "nearest",
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def _block_sad_jit(refs, frames, *, block, interpret):
     S, H, W, C = frames.shape
-    kernel = functools.partial(_block_sad_kernel, block=block)
+    mats = _sad_mats(H, W, C, block)
+    score_spec, score_shape = _scores(S)
     score = pl.pallas_call(
-        kernel,
+        _block_sad_kernel,
         grid=(S,),
-        in_specs=[_row((H, W, C)), _row((H, W, C))],
-        out_specs=pl.BlockSpec((1, 1), lambda s: (s, 0)),
-        out_shape=jax.ShapeDtypeStruct((S, 1), jnp.float32),
+        in_specs=[_row((H, W * C)), _row((H, W * C))]
+        + [_whole(m) for m in mats],
+        out_specs=score_spec,
+        out_shape=score_shape,
         interpret=interpret,
-    )(refs, frames)
-    return score[:, 0]
+    )(_planes(refs), _planes(frames), *mats)
+    return score[:, 0, 0]
 
 
 def block_sad(refs: jax.Array, frames: jax.Array, block: int = 8, *,
@@ -261,22 +330,23 @@ def block_sad(refs: jax.Array, frames: jax.Array, block: int = 8, *,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _scatter_admit_jit(batch, model, refs, gate, admit, *, interpret):
-    S = batch.shape[0]
-    bshape, gshape = batch.shape[1:], refs.shape[1:]
-    admit2d = admit.astype(jnp.int32).reshape(S, 1)
-    return pl.pallas_call(
+    batch2, refs2 = _planes(batch), _planes(refs)
+    brow, grow = _row(batch2.shape[1:]), _row(refs2.shape[1:])
+    out = pl.pallas_call(
         _scatter_kernel,
-        grid=(S,),
-        in_specs=[pl.BlockSpec((1, 1), lambda s: (s, 0)),
-                  _row(bshape), _row(bshape), _row(gshape), _row(gshape)],
-        out_specs=(_row(bshape), _row(gshape)),
-        out_shape=(jax.ShapeDtypeStruct(batch.shape, batch.dtype),
-                   jax.ShapeDtypeStruct(refs.shape, refs.dtype)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,       # the (S,) admit mask, in SMEM
+            grid=(batch.shape[0],),
+            in_specs=[brow, brow, grow, grow],
+            out_specs=(brow, grow)),
+        out_shape=(jax.ShapeDtypeStruct(batch2.shape, batch.dtype),
+                   jax.ShapeDtypeStruct(refs2.shape, refs.dtype)),
         # a TPU deployment would add input_output_aliases={1: 0, 3: 1} to
         # update the batch pool in place; kept copying here so callers (and
         # the parity harness) may reuse their inputs after the call
         interpret=interpret,
-    )(admit2d, batch, model, refs, gate)
+    )(admit.astype(jnp.int32), batch2, _planes(model), refs2, _planes(gate))
+    return out[0].reshape(batch.shape), out[1].reshape(refs.shape)
 
 
 def scatter_admit(batch: jax.Array, model: jax.Array, refs: jax.Array,

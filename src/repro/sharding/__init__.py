@@ -1,6 +1,6 @@
 """Distribution layer: logical->mesh partition rules, pipeline parallelism,
-compressed collectives, and JAX version-compat shims."""
-from repro.sharding.compat import HAS_AXIS_TYPES, auto_axis_types, make_mesh  # noqa: F401
+compressed collectives, and mesh construction."""
+from repro.sharding.compat import make_mesh  # noqa: F401
 from repro.sharding.rules import (  # noqa: F401
     axis_rules,
     batch_pspecs,

@@ -1,46 +1,22 @@
-"""JAX version-compat shims for the distribution layer.
-
-The repo targets the explicit-sharding API surface (``jax.sharding.AxisType``,
-``jax.make_mesh(..., axis_types=...)``) introduced after 0.4.x, but must run
-on whatever JAX the container bakes in.  Feature-detect once at import and
-fall back to plain mesh axes: without ``AxisType`` every axis is implicitly
-"auto", which is exactly the mode the tests and the partition rules assume,
-so behaviour is unchanged — only the newer spelling is unavailable.
-"""
+"""Mesh construction shared by the serving and distribution layers."""
 from __future__ import annotations
 
-from typing import Optional, Sequence, Tuple
+from typing import Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh
-
-AxisType = getattr(jax.sharding, "AxisType", None)
-HAS_AXIS_TYPES = AxisType is not None
-
-
-def auto_axis_types(n: int) -> Optional[tuple]:
-    """(AxisType.Auto,) * n on new JAX, None where the kwarg doesn't exist."""
-    if not HAS_AXIS_TYPES:
-        return None
-    return (AxisType.Auto,) * n
+from jax.sharding import AxisType, Mesh
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Tuple[str, ...],
-              *, axis_types="auto", devices=None, **kw) -> Mesh:
-    """``jax.make_mesh`` accepting ``axis_types`` on every JAX version.
-
-    ``axis_types="auto"`` (the default) requests Auto on all axes when the
-    installed JAX supports the concept and silently degrades to a plain mesh
-    otherwise.  Pass an explicit tuple to forward it verbatim (raises on old
-    JAX only then, since the caller truly depends on it).
+              *, devices=None, **kw) -> Mesh:
+    """``jax.make_mesh`` with every axis ``AxisType.Auto`` — the mode the
+    partition rules and the fleet's ``replica`` axis assume.
 
     ``devices=None`` takes the first ``prod(axis_shapes)`` local devices, so
     a mesh smaller than the host device pool (the fleet's ``replica`` axis
-    on an ``--xla_force_host_platform_device_count`` CPU mesh) Just Works
-    instead of requiring the caller to slice ``jax.devices()`` themselves.
+    on a four-chip host, or on an ``--xla_force_host_platform_device_count``
+    CPU mesh) needs no slicing of ``jax.devices()`` by the caller.
     """
-    if axis_types == "auto":
-        axis_types = auto_axis_types(len(tuple(axis_names)))
     if devices is None:
         n = 1
         for s in axis_shapes:
@@ -50,14 +26,6 @@ def make_mesh(axis_shapes: Sequence[int], axis_names: Tuple[str, ...],
             raise ValueError(f"mesh {tuple(axis_shapes)} needs {n} devices, "
                              f"only {len(pool)} available")
         devices = pool[:n]
-    if not hasattr(jax, "make_mesh"):
-        # pre-0.4.35 JAX: build the mesh by hand from the device grid
-        from jax.experimental import mesh_utils
-        grid = mesh_utils.create_device_mesh(tuple(axis_shapes),
-                                             devices=list(devices))
-        return Mesh(grid, tuple(axis_names))
-    if axis_types is not None:
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                             devices=devices, axis_types=axis_types, **kw)
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                         devices=devices, **kw)
+    names = tuple(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), names, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(names), **kw)

@@ -165,7 +165,12 @@ class MotionGate:
         if state is None:
             self.reset(slot)
             return
-        self.refs = self.refs.at[slot].set(state["ref"])
+        ref = state["ref"]
+        if ref.devices() != self.refs.devices():
+            # a stream rebound from a replica on another device (a
+            # shard_map fleet) brings its reference from there
+            ref = jax.device_put(ref, self.refs.sharding)
+        self.refs = self.refs.at[slot].set(ref)
         self.has_ref[slot] = state["has_ref"]
         self.thresh[slot] = state["thresh"]
         self.skip_ewma[slot] = state["skip_ewma"]

@@ -25,11 +25,11 @@ replicas in one jit containing one mapped computation per **tier group**:
     is still a single device dispatch (the 1-dispatch-per-tick contract
     ``tests/test_fleet_step`` pins);
   * ``mode="shard_map"``: ``shard_map`` over a ``mesh(("replica",))``
-    built with ``sharding/compat.make_mesh``; each device executes exactly
-    the single-replica program (the mapped body indexes away its size-1
-    replica block), so per-replica math is token-for-token the serial
-    program and results are bit-identical.  Requires a single tier group
-    (a mesh axis cannot mix program shapes);
+    (:func:`replica_mesh`): one replica per device, or ``R / D`` replicas
+    per device when the fleet outnumbers the ``D`` devices, each device
+    vmapping the same body over its block.  Each replica's batch pools and
+    gate refs stay on its own device between ticks.  Requires a single
+    tier group (a mesh axis cannot mix program shapes);
   * ``mode="vmap"``: the same stacked state through ``jax.vmap`` of the
     same body — the single-device / CPU / interpret fallback and the only
     mode for mixed-tier fleets.
@@ -63,12 +63,13 @@ from __future__ import annotations
 
 import functools
 import time
+import warnings
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.core.clock import VirtualClock
 from repro.models import vision as V
@@ -80,15 +81,8 @@ from repro.streams.vision_engine import (INNER, OUTER, VisionServeEngine,
 MODES = ("shard_map", "vmap")
 
 
-def _shard_map():
-    if hasattr(jax, "shard_map"):                 # jax >= 0.6 spelling
-        return jax.shard_map
-    from jax.experimental.shard_map import shard_map
-    return shard_map
-
-
 def resolve_mode(n_replicas: int, mode: Optional[str] = None) -> str:
-    """``shard_map`` on a real accelerator mesh with enough devices,
+    """``shard_map`` on an accelerator host with more than one device,
     ``vmap`` otherwise — same stacked state and mapped body either way.
 
     Forced host-platform CPU devices (``XLA_FLAGS=--xla_force_host_
@@ -103,10 +97,24 @@ def resolve_mode(n_replicas: int, mode: Optional[str] = None) -> str:
         if mode not in MODES:
             raise ValueError(f"mode must be one of {MODES}, got {mode!r}")
         return mode
-    if (n_replicas > 1 and len(jax.devices()) >= n_replicas
+    if (n_replicas > 1 and jax.device_count() > 1
             and jax.default_backend() != "cpu"):
         return "shard_map"
     return "vmap"
+
+
+def replica_mesh(n_replicas: int):
+    """The ``("replica",)`` mesh a shard_map fleet runs on: one replica
+    per device up to the device count, and past it ``R / D`` replicas per
+    device, which each device vmaps.  A fleet that cannot be spread evenly
+    raises instead of piling onto one device."""
+    n = min(n_replicas, jax.device_count())
+    if n_replicas % n:
+        raise ValueError(
+            f"{n_replicas} replicas cannot be spread evenly over "
+            f"{jax.device_count()} devices: use a multiple of the device "
+            f"count, or mode='vmap' to run the fleet on one device")
+    return make_mesh((n,), ("replica",))
 
 
 def _stack_trees(trees: Sequence[dict]):
@@ -198,19 +206,8 @@ def _build_fused(mode: str, mesh, members: Tuple[Tuple[int, ...], ...],
 
     if mode == "shard_map":
         assert len(singles) == 1, "shard_map requires one tier group"
-        spec = PartitionSpec("replica")
-        single = singles[0]
-
-        def shard_body(ops):
-            # each device holds a size-1 replica block: index it away,
-            # run the per-replica program, restore the leading axis
-            res = single(jax.tree_util.tree_map(lambda x: x[0], ops))
-            return jax.tree_util.tree_map(lambda x: x[None], res)
-
-        mapped = [_shard_map()(shard_body, mesh=mesh, in_specs=spec,
-                               out_specs=spec, check_rep=False)]
-    else:
-        mapped = [jax.vmap(s) for s in singles]
+        return _build_sharded(singles[0], mesh, R)
+    mapped = [jax.vmap(s) for s in singles]
 
     # replica order of the group-concatenated rows, and its inverse: the
     # gather that restores replica order for the fleet-wide mask output
@@ -249,6 +246,43 @@ def _build_fused(mode: str, mesh, members: Tuple[Tuple[int, ...], ...],
                     rows[i] = v[j]
         for key, rows in per_rep.items():
             res[key] = tuple(rows)
+        return res
+
+    return jax.jit(fused)
+
+
+def _build_sharded(single, mesh, n_replicas: int):
+    """The fused tick over the ``("replica",)`` mesh: device ``d`` runs
+    replicas ``d*k .. d*k+k-1`` (``k = R / mesh size``) by vmapping the
+    per-replica body over its block.
+
+    Per-replica state (batch pools, gate refs) crosses the jit boundary
+    as ``k`` flat ``(mesh size * slots, ...)`` arrays whose device-``d``
+    shard is exactly one replica's ``(slots, ...)`` array, so it is
+    assembled from, and split back into, the engines' own single-device
+    arrays without a copy: each replica's state stays on its device.
+    Everything else (stage frames, masks, thresholds, stacked params) is
+    one ``(R, ...)`` array sharded along the replica axis."""
+    k = n_replicas // mesh.size
+
+    def shard_body(ops):
+        stacked = {key: jnp.stack(v) if isinstance(v, tuple) else v
+                   for key, v in ops.items()}
+        out = jax.vmap(single)(stacked)
+        return {key: (v if key.startswith(("admit", "flags"))
+                      else tuple(v[j] for j in range(k)))
+                for key, v in out.items()}
+
+    spec = PartitionSpec("replica")
+    mapped = jax.shard_map(shard_body, mesh=mesh, in_specs=spec,
+                           out_specs=spec, check_vma=False)
+
+    def fused(ops):
+        out = mapped(ops)
+        res = {key: v for key, v in out.items()
+               if not key.startswith(("admit", "flags"))}
+        res["masks"] = jnp.stack([out[f"admit_{OUTER}"], out[f"admit_{INNER}"],
+                                  out[f"flags_{OUTER}"], out[f"flags_{INNER}"]])
         return res
 
     return jax.jit(fused)
@@ -312,9 +346,11 @@ class FleetStep:
                     "shard_map maps one program over the replica mesh and "
                     "cannot mix tier geometries; mixed-tier fleets run "
                     "mode='vmap'")
-            self.mode = "vmap"          # auto-resolved: fall back quietly
-        self.mesh = (make_mesh((R,), ("replica",))
-                     if self.mode == "shard_map" else None)
+            warnings.warn(
+                f"mixed-tier fleet of {R} replicas runs mode='vmap' on one "
+                f"of {jax.device_count()} devices", stacklevel=3)
+            self.mode = "vmap"
+        self.mesh = replica_mesh(R) if self.mode == "shard_map" else None
         # one pinned staging buffer per tier group; each engine's _stage
         # is a view of its group row, so the host never copies frames
         # again and the fused call uploads each group's staging in one
@@ -343,9 +379,14 @@ class FleetStep:
         self._false_gs = [np.zeros((len(mem), self.slots), bool)
                           for mem in self._members]
         self._mem_idx = [np.asarray(mem, int) for mem in self._members]
+        if self.mesh is not None:
+            self._place_on_mesh()
         self._fused = self._build()
         self.dispatches = 0            # fused device dispatches issued
         self.last_dispatch_s = 0.0     # wall time of the last fused call
+        # the last fused call's (admit_outer, admit_inner, flags_outer,
+        # flags_inner) masks, (4, R, slots) — what every mode must agree on
+        self.last_masks: Optional[np.ndarray] = None
         if warm:
             self._warm()
 
@@ -362,9 +403,66 @@ class FleetStep:
             ref._interpret if self.use_pallas else False)
 
     # ------------------------------------------------------------------
+    # replica mesh placement (shard_map mode: one tier group)
+    # ------------------------------------------------------------------
+    def _place_on_mesh(self) -> None:
+        """Commit every replica's device state to its mesh device and the
+        stacked params to the replica sharding, once: from here on each
+        tick's state only moves between a replica's engine and its own
+        device's shard of the fused call (see :func:`_build_sharded`)."""
+        self._sharding = NamedSharding(self.mesh, PartitionSpec("replica"))
+        self._k = len(self.replicas) // self.mesh.size
+        devices = list(self.mesh.devices.flat)
+        for i, r in enumerate(self.replicas):
+            dev = devices[i // self._k]
+            for kind in (OUTER, INNER):
+                r.batches[kind] = jax.device_put(r.batches[kind], dev)
+                if self.use_gate:
+                    g = r.gates[kind]
+                    g.refs = jax.device_put(g.refs, dev)
+        self._dp = [jax.device_put(self._dp[0], self._sharding)]
+        self._pp = [jax.device_put(self._pp[0], self._sharding)]
+        self._null_refs = [tuple(
+            jax.device_put(x, devices[i // self._k])
+            for i, x in enumerate(self._null_refs[0]))]
+
+    def _to_mesh(self, ops: Dict[str, object]) -> Dict[str, object]:
+        """One group's gathered operands as the sharded call's arguments:
+        per-replica arrays become ``k`` flat arrays assembled from the
+        replicas' own buffers, host arrays one sharded upload each."""
+        n, k = self.mesh.size, self._k
+        out: Dict[str, object] = {}
+        host: Dict[str, np.ndarray] = {}
+        for key, v in ops.items():
+            if isinstance(v, tuple):
+                shape = (n * v[0].shape[0],) + v[0].shape[1:]
+                out[key] = tuple(
+                    jax.make_array_from_single_device_arrays(
+                        shape, self._sharding, [v[d * k + j] for d in range(n)])
+                    for j in range(k))
+            elif isinstance(v, np.ndarray):
+                host[key] = v
+            else:
+                out[key] = v                      # params, placed at init
+        out.update(jax.device_put(host, self._sharding))
+        return out
+
+    def _per_replica(self, v) -> Sequence[jax.Array]:
+        """A fused-call state output as one array per replica, in replica
+        order (shard_map: each replica's shard, still on its device)."""
+        if self.mesh is None:
+            return v
+        rows: List[Optional[jax.Array]] = [None] * len(self.replicas)
+        for j, a in enumerate(v):
+            for sh in a.addressable_shards:
+                d = (sh.index[0].start or 0) // self.slots
+                rows[d * self._k + j] = sh.data
+        return rows
+
+    # ------------------------------------------------------------------
     # host orchestration
     # ------------------------------------------------------------------
-    def _gather(self, act: Dict[str, np.ndarray]) -> List[Dict[str, object]]:
+    def _gather(self, act: Dict[str, np.ndarray]):
         """Collect per-group engine state for the fused call (tuples of
         device arrays + host numpy masks; stacking happens inside the jit).
         """
@@ -388,6 +486,8 @@ class FleetStep:
                     ops[f"href_{kind}"] = self._false_gs[g]
                 ops[f"act_{kind}"] = act[kind][self._mem_idx[g]]
             gops.append(ops)
+        if self.mesh is not None:
+            return self._to_mesh(gops[0])
         return gops
 
     def _warm(self) -> None:
@@ -432,6 +532,9 @@ class FleetStep:
                             n_active=int(act[OUTER].sum()
                                          + act[INNER].sum()))
             masks = np.asarray(out["masks"])              # (4, R, slots)
+            self.last_masks = masks
+            state = {key: self._per_replica(v) for key, v in out.items()
+                     if key != "masks"}
             admit = {OUTER: masks[0], INNER: masks[1]}
             flags = {OUTER: masks[2], INNER: masks[3]}
             total = int(admit[OUTER].sum() + admit[INNER].sum())
@@ -444,9 +547,9 @@ class FleetStep:
                     if a_row.any():
                         # serial parity: state only refreshes where the
                         # serial path would have dispatched this class
-                        r.batches[kind] = out[f"batch_{kind}"][i]
+                        r.batches[kind] = state[f"batch_{kind}"][i]
                         if self.use_gate:
-                            r.gates[kind].refs = out[f"refs_{kind}"][i]
+                            r.gates[kind].refs = state[f"refs_{kind}"][i]
                     dt = (wall * int(m_row.sum()) / total
                           if on_wall and total else None)
                     if dt is not None:

@@ -205,3 +205,48 @@ def test_shard_map_mode_parity_on_forced_device_mesh():
                           timeout=600)
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert "SHARD_MAP_PARITY_OK" in proc.stdout
+
+
+_SPREAD_PROBE = """
+import jax
+import pytest
+assert len(jax.devices()) == 2, jax.devices()
+from repro.simulate import ReplicaSpec, get_scenario, run_scenario
+from repro.simulate.runner import ScenarioRunner
+from repro.streams.fleet_step import replica_mesh
+# 4 replicas on 2 devices: each device vmaps two; r1 fails at tick 60 and
+# its streams rebind onto replicas of both devices, gate refs included
+s = get_scenario("replica_failure", ticks=80,
+                 replicas=tuple(ReplicaSpec(f"r{i}", slots=4)
+                                for i in range(4)))
+ser = run_scenario(s)
+runner = ScenarioRunner(s, parallel=True, fleet_mode="shard_map")
+par = runner.run()
+assert not par.violations, par.violations
+assert ser.summary["rebinds"] > 0
+assert ser.digest == par.digest, (ser.digest, par.digest)
+devs = [sorted(d.id for d in r.batches["outer"].devices())
+        for r in runner.gw.replicas]
+assert devs == [[0], [0], [1], [1]], devs
+with pytest.raises(ValueError, match="spread evenly"):
+    replica_mesh(3)
+print("SPREAD_PARITY_OK")
+"""
+
+
+@pytest.mark.slow
+def test_shard_map_spreads_replicas_over_fewer_devices():
+    """A fleet larger than the device count spreads R/D replicas per
+    device (never all on device 0), keeps each replica's state on its
+    device across rebinds, matches the serial digest, and refuses a fleet
+    that does not divide evenly."""
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
+                        + " --xla_force_host_platform_device_count=2")
+    env["PYTHONPATH"] = (os.path.abspath("src") + os.pathsep
+                         + env.get("PYTHONPATH", ""))
+    proc = subprocess.run([sys.executable, "-c", _SPREAD_PROBE],
+                          capture_output=True, text=True, env=env,
+                          timeout=600)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert "SPREAD_PARITY_OK" in proc.stdout
