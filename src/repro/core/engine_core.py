@@ -430,6 +430,8 @@ class EngineCore:
         children are resolved once here, never per tick."""
         if tracer is not None:
             self.tracer = tracer
+            if not tracer.enabled:
+                self._tick_tracer = tracer      # detached: no span after
         if metrics is not None:
             self.metrics = metrics
         m = self.metrics
@@ -493,18 +495,22 @@ class EngineCore:
         # sample-select the tick's tracer BEFORE rebalance, so admission
         # work done in the rebalance hook (token prefill) is covered
         self._tick_tracer = self.tracer.for_tick(self.ticks)
-        self.rebalance()
+        with self.tspan("rebalance"):
+            self.rebalance()
         t0 = self.clock.now_s()
         self.clock.charge(TICK)                  # fixed per-tick overhead
         return t0
 
-    def end_tick(self, t0_s: float, done: int) -> None:
-        """Tick-cost EWMA + tick counter — the closing half of a tick."""
+    def end_tick(self, t0_s: float, done: int, *, span: bool = True) -> None:
+        """Tick-cost EWMA + tick counter — the closing half of a tick.
+        ``span=False`` leaves out the ``tick`` span: the fused fleet tick
+        records one ``fleet.tick`` for all replicas instead of R copies
+        of the same interval."""
         dt_ms = (self.clock.now_s() - t0_s) * 1000.0
         if done:
             self.tick_cost_ms.update(dt_ms)
         tr = self._tick_tracer
-        if tr.enabled:
+        if span and tr.enabled:
             tr.complete("tick", self.name, t0_s, dt_ms / 1000.0,
                         tick=self.ticks, done=done)
         if self._m_ticks is not None:

@@ -28,11 +28,62 @@ Two properties make this usable inside the deterministic simulator:
 Memory is bounded: past ``max_events`` the tracer stops recording and
 counts drops (``dropped``) instead of growing without bound — a trace is
 a debugging artifact, not a ledger.
+
+**A clock shared with the device profiler.**  Span timestamps stay on the
+engine clock (``time.perf_counter`` under a ``WallClock``), which no
+profiler reads.  While a tracer is attached to a wall-clocked fleet it
+keeps clock anchors, pairs of ``(perf_counter_ns, time_ns)`` read back
+to back (:func:`clock_anchor`): one at attach, one at each export.  The
+JAX profiler dates its session in unix ns (``profile_start_time`` in the
+trace's ``Task Environment`` plane) and its device events from that
+start, so :func:`to_unix_ns`, a linear interpolation between the first
+and the last anchor (which also absorbs drift between
+``CLOCK_MONOTONIC`` and ``CLOCK_REALTIME`` over a window), puts every
+span on the device trace's clock with no fit.  Virtual clocks keep no
+anchor: their time is nobody's wall time.  On a wall-clocked fleet the
+tracer also records one ``gc`` span per garbage collection on a
+``python`` lane (``gc.callbacks``), while attached and on sampled ticks.
 """
 from __future__ import annotations
 
+import gc
 import json
-from typing import Dict, List, Optional
+import time
+import weakref
+from typing import Dict, List, Optional, Sequence
+
+ANCHOR_READS = 5          # back-to-back reads per anchor; the tightest wins
+
+
+def clock_anchor(reads: int = ANCHOR_READS) -> dict:
+    """One ``perf_counter`` / unix-time pair: of ``reads`` back-to-back
+    ``perf_counter_ns, time_ns, perf_counter_ns`` triples the one whose
+    two ``perf_counter`` reads lie closest, dated at their midpoint, with
+    that gap (the pair's uncertainty)."""
+    best: Optional[dict] = None
+    for _ in range(reads):
+        a = time.perf_counter_ns()
+        u = time.time_ns()
+        b = time.perf_counter_ns()
+        if best is None or b - a < best["gap_ns"]:
+            best = {"perf_ns": (a + b) // 2, "unix_ns": u, "gap_ns": b - a}
+    return best
+
+
+def to_unix_ns(anchors: Sequence[dict], perf_s: float) -> int:
+    """A ``perf_counter`` reading (seconds, a span's clock) in unix ns,
+    interpolated between the first and the last anchor (one anchor:
+    a plain offset)."""
+    a, b = anchors[0], anchors[-1]
+    p = perf_s * 1e9 - a["perf_ns"]
+    span = b["perf_ns"] - a["perf_ns"]
+    rate = (b["unix_ns"] - a["unix_ns"]) / span if span > 0 else 1.0
+    return a["unix_ns"] + round(p * rate)
+
+
+def _unhook(hook) -> None:
+    if hook in gc.callbacks:
+        gc.callbacks.remove(hook)
 
 
 class _NullSpan:
@@ -63,6 +114,12 @@ class NullTracer:
 
     def for_tick(self, tick: int) -> "NullTracer":
         return self
+
+    def attach(self, wall_clock: bool) -> None:
+        return None
+
+    def detach(self) -> None:
+        return None
 
     def span(self, clock, name: str, tid: str = "main", **args) -> _NullSpan:
         return NULL_SPAN
@@ -120,6 +177,52 @@ class SpanTracer:
         self.events: List[dict] = []
         self.dropped = 0
         self._tids: Dict[str, int] = {}
+        self.anchors: List[dict] = []   # (perf, unix) pairs, see module doc
+        self._attached = 0              # fleets this tracer is attached to
+        self._gc_hook = None            # installed in gc.callbacks, or None
+        self._gc_t0: Optional[float] = None
+        self._sampled = True            # the last for_tick's decision
+
+    # ------------------------------------------------------------------
+    # attachment: clock anchors and the collector's lane
+    # ------------------------------------------------------------------
+    def attach(self, wall_clock: bool) -> None:
+        """A fleet takes this tracer.  On a wall clock: an anchor, and
+        the ``gc`` lane while any fleet holds the tracer."""
+        self._attached += 1
+        if not wall_clock:
+            return
+        self.anchors.append(clock_anchor())
+        if self._gc_hook is None:
+            self._tid("python")     # named before a collection can need it
+            ref = weakref.ref(self)
+
+            def hook(phase: str, info: dict) -> None:
+                tracer = ref()
+                if tracer is not None:
+                    tracer._on_gc(phase, info)
+
+            self._gc_hook = hook
+            gc.callbacks.append(hook)
+            weakref.finalize(self, _unhook, hook)
+
+    def detach(self) -> None:
+        """A fleet lets go of this tracer; the last one removes the
+        ``gc`` lane's hook."""
+        self._attached = max(self._attached - 1, 0)
+        if not self._attached and self._gc_hook is not None:
+            _unhook(self._gc_hook)
+            self._gc_hook = None
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._gc_t0 = time.perf_counter()
+        elif self._gc_t0 is not None:
+            t0, self._gc_t0 = self._gc_t0, None
+            if self._sampled:
+                self.complete("gc", "python", t0, time.perf_counter() - t0,
+                              generation=info["generation"],
+                              collected=info["collected"])
 
     # ------------------------------------------------------------------
     # recording
@@ -127,7 +230,8 @@ class SpanTracer:
     def for_tick(self, tick: int):
         """The tracer an engine should route this tick's phase spans
         through: self on sampled ticks, the null tracer otherwise."""
-        return self if tick % self.sample_every == 0 else NULL_TRACER
+        self._sampled = tick % self.sample_every == 0
+        return self if self._sampled else NULL_TRACER
 
     def _tid(self, name: str) -> int:
         tid = self._tids.get(name)
@@ -172,10 +276,16 @@ class SpanTracer:
     # export
     # ------------------------------------------------------------------
     def to_chrome(self) -> dict:
-        """The Chrome trace-event JSON object (Perfetto-loadable)."""
+        """The Chrome trace-event JSON object (Perfetto-loadable).
+        ``otherData.clock_anchors`` holds the attach anchors and one read
+        now, or nothing where no wall-clocked fleet held the tracer."""
+        anchors = list(self.anchors)
+        if anchors:
+            anchors.append(clock_anchor())
         return {"traceEvents": list(self.events),
                 "displayTimeUnit": "ms",
-                "otherData": {"dropped_events": self.dropped}}
+                "otherData": {"dropped_events": self.dropped,
+                              "clock_anchors": anchors}}
 
     def dump(self, path: str) -> None:
         with open(path, "w") as f:

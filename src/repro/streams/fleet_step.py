@@ -58,6 +58,16 @@ recompiles.
 Under virtual clocks (``repro.simulate``) the parallel tick is
 bit-identical to the serial tick: same admit decisions, same ledger
 records, same golden-trace digests (pinned by ``tests/test_fleet_step``).
+
+Spans (``obs.tracing``, on the ``fleet`` lane and on the lead live
+replica's clock and sampled tick): ``fleet.tick`` around the whole tick;
+``fused_dispatch`` over exactly the interval ``last_dispatch_s`` times,
+with ``fleet.gather`` / ``fleet.call`` (the jit call returning, ``bytes``
+staged) / ``fleet.wait`` (``block_until_ready``) inside it; then
+``fleet.readback`` (the masks to the host), ``fleet.commit`` (state
+unstacked, every ``commit_class``) and ``fleet.end`` (``end_tick`` and
+the scheduler's feedback).  The replicas' own lanes hold ``rebalance``,
+``stage`` and ``commit``.
 """
 from __future__ import annotations
 
@@ -73,6 +83,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.core.clock import VirtualClock
 from repro.models import vision as V
+from repro.obs.tracing import NULL_TRACER
 from repro.sharding.compat import make_mesh
 from repro.streams import filter as sfilter
 from repro.streams.vision_engine import (INNER, OUTER, VisionServeEngine,
@@ -379,6 +390,8 @@ class FleetStep:
         self._false_gs = [np.zeros((len(mem), self.slots), bool)
                           for mem in self._members]
         self._mem_idx = [np.asarray(mem, int) for mem in self._members]
+        self._staged_bytes = sum(b.nbytes for b in self._stage_groups)
+        self.tracer = NULL_TRACER      # the gateway's, set by attach_obs
         if self.mesh is not None:
             self._place_on_mesh()
         self._fused = self._build()
@@ -504,8 +517,16 @@ class FleetStep:
         """One fleet tick with serial semantics: identical host phases per
         live replica around a single fused device dispatch.  ``gw`` is the
         owning ``FleetGateway`` (scheduler feedback + dead-replica set)."""
-        R = len(self.replicas)
         live = [r for r in self.replicas if r.name not in gw.dead]
+        lead = live[0] if live else self.replicas[0]
+        clk = lead.clock
+        # the lead replica's begin_tick samples this same tick number
+        tr = self.tracer.for_tick(lead.ticks)
+        with tr.span(clk, "fleet.tick", tid="fleet", tick=lead.ticks):
+            return self._tick(gw, live, tr, clk)
+
+    def _tick(self, gw, live, tr, clk) -> int:
+        R = len(self.replicas)
         t0s = {r.name: r.begin_tick() for r in live}
         act = {OUTER: np.zeros((R, self.slots), bool),
                INNER: np.zeros((R, self.slots), bool)}
@@ -518,61 +539,53 @@ class FleetStep:
         per_done = {r.name: 0 for r in live}
         wall_share_s = {r.name: 0.0 for r in live}
         if act[OUTER].any() or act[INNER].any():
+            on_wall = not isinstance(clk, VirtualClock)
             wall0 = time.perf_counter()
-            out = jax.block_until_ready(self._fused(self._gather(act)))
+            # the span's start: the same read on a wall clock, the
+            # clock's own time on a virtual one (read only when traced)
+            t_span = wall0 if on_wall or not tr.enabled else clk.now_s()
+            with tr.span(clk, "fleet.gather", tid="fleet"):
+                ops = self._gather(act)
+            with tr.span(clk, "fleet.call", tid="fleet",
+                         bytes=self._staged_bytes):
+                out = self._fused(ops)
+            with tr.span(clk, "fleet.wait", tid="fleet"):
+                out = jax.block_until_ready(out)
             wall = time.perf_counter() - wall0
             self.dispatches += 1
             self.last_dispatch_s = wall
-            tr = getattr(gw, "tracer", None)
-            if tr is not None and tr.enabled:
-                # one fleet-lane span per fused dispatch: anchored at the
-                # lead replica's tick start, duration = measured host wall
-                tr.complete("fused_dispatch", "fleet", t0s[live[0].name],
-                            wall, dispatch=self.dispatches,
+            if tr.enabled:
+                tr.complete("fused_dispatch", "fleet", t_span,
+                            wall if on_wall else clk.now_s() - t_span,
+                            dispatch=self.dispatches,
                             n_active=int(act[OUTER].sum()
                                          + act[INNER].sum()))
-            masks = np.asarray(out["masks"])              # (4, R, slots)
+            with tr.span(clk, "fleet.readback", tid="fleet"):
+                masks = np.asarray(out["masks"])          # (4, R, slots)
             self.last_masks = masks
-            state = {key: self._per_replica(v) for key, v in out.items()
-                     if key != "masks"}
-            admit = {OUTER: masks[0], INNER: masks[1]}
-            flags = {OUTER: masks[2], INNER: masks[3]}
-            total = int(admit[OUTER].sum() + admit[INNER].sum())
-            for i, r in enumerate(self.replicas):
-                if r.name in gw.dead:
-                    continue
-                on_wall = not isinstance(r.clock, VirtualClock)
-                for kind in (OUTER, INNER):
-                    a_row, m_row = act[kind][i], admit[kind][i]
-                    if a_row.any():
-                        # serial parity: state only refreshes where the
-                        # serial path would have dispatched this class
-                        r.batches[kind] = state[f"batch_{kind}"][i]
-                        if self.use_gate:
-                            r.gates[kind].refs = state[f"refs_{kind}"][i]
-                    dt = (wall * int(m_row.sum()) / total
-                          if on_wall and total else None)
-                    if dt is not None:
-                        wall_share_s[r.name] += dt
-                    per_done[r.name] += r.commit_class(
-                        kind, a_row, m_row, flags[kind][i], dt_share_s=dt)
+            with tr.span(clk, "fleet.commit", tid="fleet"):
+                self._commit(gw, out, masks, act, wall, per_done,
+                             wall_share_s)
 
         done = 0
-        for r in live:
-            n = per_done[r.name]
-            r.end_tick(t0s[r.name], n)
-            if n:
-                if isinstance(r.clock, VirtualClock):
-                    # same reads/charges as the serial path: bit-identical
-                    dt_ms = (r.clock.now_s() - t0s[r.name]) * 1000.0
-                else:
-                    # wall clocks: the elapsed time since t0 spans the
-                    # WHOLE fleet's host+device work — feed the capacity
-                    # EWMA this replica's share of the fused dispatch
-                    # instead, matching serial observe semantics
-                    dt_ms = wall_share_s[r.name] * 1000.0
-                gw.sched.by_name(r.name).observe(n, dt_ms)
-            done += n
+        with tr.span(clk, "fleet.end", tid="fleet"):
+            for r in live:
+                n = per_done[r.name]
+                r.end_tick(t0s[r.name], n, span=False)
+                if n:
+                    if isinstance(r.clock, VirtualClock):
+                        # same reads/charges as the serial path:
+                        # bit-identical
+                        dt_ms = (r.clock.now_s() - t0s[r.name]) * 1000.0
+                    else:
+                        # wall clocks: the elapsed time since t0 spans the
+                        # WHOLE fleet's host+device work — feed the
+                        # capacity EWMA this replica's share of the fused
+                        # dispatch instead, matching serial observe
+                        # semantics
+                        dt_ms = wall_share_s[r.name] * 1000.0
+                    gw.sched.by_name(r.name).observe(n, dt_ms)
+                done += n
         if gw.token_replicas:
             # mixed fleets: the fused dispatch covers the vision replicas;
             # token decode runs its own shared jits, stepped with the
@@ -584,3 +597,31 @@ class FleetStep:
             # reorder pool allocation between serial and parallel ticks.
             done += gw._tick_tokens()
         return done
+
+    def _commit(self, gw, out, masks, act, wall, per_done,
+                wall_share_s) -> None:
+        """Hand each live replica its state back and run its host
+        bookkeeping for both classes (``commit_class``)."""
+        state = {key: self._per_replica(v) for key, v in out.items()
+                 if key != "masks"}
+        admit = {OUTER: masks[0], INNER: masks[1]}
+        flags = {OUTER: masks[2], INNER: masks[3]}
+        total = int(admit[OUTER].sum() + admit[INNER].sum())
+        for i, r in enumerate(self.replicas):
+            if r.name in gw.dead:
+                continue
+            on_wall = not isinstance(r.clock, VirtualClock)
+            for kind in (OUTER, INNER):
+                a_row, m_row = act[kind][i], admit[kind][i]
+                if a_row.any():
+                    # serial parity: state only refreshes where the
+                    # serial path would have dispatched this class
+                    r.batches[kind] = state[f"batch_{kind}"][i]
+                    if self.use_gate:
+                        r.gates[kind].refs = state[f"refs_{kind}"][i]
+                dt = (wall * int(m_row.sum()) / total
+                      if on_wall and total else None)
+                if dt is not None:
+                    wall_share_s[r.name] += dt
+                per_done[r.name] += r.commit_class(
+                    kind, a_row, m_row, flags[kind][i], dt_share_s=dt)

@@ -38,10 +38,12 @@ from typing import TYPE_CHECKING, Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
+from repro.core.clock import VirtualClock
 from repro.core.scheduler import (Assignment, CapacityScheduler,
                                   HardwareInfo, WorkerState)
 from repro.core.segmentation import Segment
 from repro.core.telemetry import Ledger, SegmentRecord
+from repro.obs.tracing import NULL_TRACER
 from repro.streams.vision_engine import INNER, OUTER, VisionServeEngine
 
 if TYPE_CHECKING:                                     # pragma: no cover
@@ -147,13 +149,13 @@ class FleetGateway:
         self.deadline_ms = deadline_ms
         self.overcommit = overcommit
         self.ledger = ledger if ledger is not None else Ledger()
-        # fleet-wide observability plane: every replica shares one
-        # registry/tracer, exactly like the shared ledger above
-        self.metrics = metrics
-        self.tracer = tracer
+        # fleet-wide observability plane (attach_obs, at the end of
+        # construction): every replica shares one registry/tracer,
+        # exactly like the shared ledger here
+        self.metrics = None
+        self.tracer = NULL_TRACER
         for r in self.replicas:
             r.ledger = self.ledger            # one fleet-wide ledger
-            r.attach_obs(metrics=metrics, tracer=tracer)
 
         # replica heterogeneity enters through the HW prior; measurement
         # (frames/s per tick) refines it exactly like the phone handshake
@@ -220,7 +222,6 @@ class FleetGateway:
                                  f"vision and token fleets: {names}")
             for e in self.token_replicas:
                 e.ledger = self.ledger        # one fleet-wide ledger
-                e.attach_obs(metrics=metrics, tracer=tracer)
                 self._token_by_name[e.name] = e
                 self._token_harvested[e.name] = 0
             tstates = [WorkerState(name=e.name,
@@ -243,7 +244,26 @@ class FleetGateway:
             for e in self.token_replicas:
                 e.emitter = events.new_emitter(e.name)
 
+        self.attach_obs(metrics=metrics, tracer=tracer)
+
+    def attach_obs(self, metrics=None, tracer=None) -> None:
+        """(Re)attach the observability plane to every replica and to the
+        fused fleet step: a shared ``MetricsRegistry`` and/or a
+        ``SpanTracer``.  ``tracer=NULL_TRACER`` detaches the tracer, so a
+        caller can trace one stretch of a run only.  A tracer attached to
+        a wall-clocked fleet keeps clock anchors and the ``gc`` lane
+        (``obs.tracing``)."""
+        if tracer is not None and tracer is not self.tracer:
+            self.tracer.detach()
+            self.tracer = tracer
+            tracer.attach(wall_clock=not isinstance(self.replicas[0].clock,
+                                                    VirtualClock))
+            if self._fleet is not None:
+                self._fleet.tracer = tracer
+        for e in [*self.replicas, *self.token_replicas]:
+            e.attach_obs(metrics=metrics, tracer=tracer)
         if metrics is not None:
+            self.metrics = metrics
             from repro.obs.probes import register_runtime_gauges
             register_runtime_gauges(metrics, self)
 
@@ -603,11 +623,18 @@ class FleetGateway:
         across many cell gateways, and the region must pump it exactly
         once per region tick — per-cell pumps would multiply the backoff
         round counter and the delivery cadence."""
+        pump = self.events is not None and pump_events
+        tr, clk = NULL_TRACER, None
+        if self.tracer.enabled and (self.tiering is not None or pump):
+            # the gateway lane samples with the lead replica's tick
+            lead = (self.live_replicas() or self.replicas)[0]
+            tr, clk = self.tracer.for_tick(lead.ticks), lead.clock
         if self.tiering is not None:
             # the tier control round runs before any engine work, reading
             # only host state — so serial and mesh-parallel fleets make
             # identical migration/scale decisions
-            self.tiering.step(self)
+            with tr.span(clk, "tiers", tid="gateway"):
+                self.tiering.step(self)
         if self._fleet is not None:
             done = self._fleet.tick(self)
         else:
@@ -621,11 +648,12 @@ class FleetGateway:
                 done += n
             if self.token_replicas:
                 done += self._tick_tokens()
-        if self.events is not None and pump_events:
+        if pump:
             # one delivery round per gateway tick, after all engine work
             # — shared by both modes so attaching the plane cannot fork
             # serial vs mesh-parallel traces
-            self.events.pump()
+            with tr.span(clk, "events.pump", tid="gateway"):
+                self.events.pump()
         return done
 
     def drain(self, max_ticks: int = 100_000) -> int:

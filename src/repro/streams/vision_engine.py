@@ -558,7 +558,8 @@ class VisionServeEngine(EngineCore):
         t0 = self.clock.now_s()
         with self.tspan("forward", cls=kind):
             per_frame = self._forward(kind, batch)
-        return self._finish_class(admit, per_frame, t0, n_admit)
+        with self.tspan("commit", n=n_admit):
+            return self._finish_class(admit, per_frame, t0, n_admit)
 
     def _forward(self, kind: str, batch: jax.Array) -> np.ndarray:
         """Model dispatch for one class; returns (slots,) per-lane flags."""
@@ -575,28 +576,28 @@ class VisionServeEngine(EngineCore):
         clock charge, cost EWMAs (core ``finish_dispatch``), per-stream
         counters/flags/timestamps.  ``dt_override_s`` carries a fleet-
         parallel replica's share of the measured fused wall time (a
-        virtual clock never passes it — its charge IS the cost)."""
-        with self.tspan("commit", n=n_admit):
-            dt = self.finish_dispatch(n_admit, t0_s, FRAME,
-                                      dt_override_s=dt_override_s)
+        virtual clock never passes it — its charge IS the cost).  The
+        caller holds the ``commit`` span."""
+        dt = self.finish_dispatch(n_admit, t0_s, FRAME,
+                                  dt_override_s=dt_override_s)
 
-            now = self.clock.now_s()
-            for lane in np.nonzero(admit)[0]:
-                st = self.lanes[lane]
-                st.processed += 1
-                st.last_s = now
-                st.processing_ms += dt * 1000.0 / n_admit
-                flag = bool(per_frame[lane])
-                st.flagged += flag
-                self.results[st.key].append(flag)
-                if flag and self.emitter is not None:
-                    # detection -> alert: the just-processed frame's
-                    # ordinal is consumed-1 (processed was incremented)
-                    self.emitter.emit(
-                        st.key,
-                        HAZARD if st.kind == OUTER else DISTRACTION,
-                        st.consumed - 1, emit_s=now, lane=int(lane))
-            self.frames_processed += n_admit
+        now = self.clock.now_s()
+        for lane in np.nonzero(admit)[0]:
+            st = self.lanes[lane]
+            st.processed += 1
+            st.last_s = now
+            st.processing_ms += dt * 1000.0 / n_admit
+            flag = bool(per_frame[lane])
+            st.flagged += flag
+            self.results[st.key].append(flag)
+            if flag and self.emitter is not None:
+                # detection -> alert: the just-processed frame's
+                # ordinal is consumed-1 (processed was incremented)
+                self.emitter.emit(
+                    st.key,
+                    HAZARD if st.kind == OUTER else DISTRACTION,
+                    st.consumed - 1, emit_s=now, lane=int(lane))
+        self.frames_processed += n_admit
         return n_admit
 
     def commit_class(self, kind: str, active: np.ndarray, admit: np.ndarray,
@@ -609,19 +610,21 @@ class VisionServeEngine(EngineCore):
         applies exactly the accounting :meth:`_step_class` applies after
         its own serial dispatch — gate controller replay, gated counters,
         clock charges, per-stream stats — so the two paths stay
-        bit-identical under virtual clocks."""
-        gate = self.gates[kind]
-        if gate is not None and active.any():
-            gate.commit_decision(active, admit)
-        for lane in np.nonzero(active & ~admit)[0]:
-            self.lanes[lane].gated += 1
+        bit-identical under virtual clocks.  Its ``commit`` span covers
+        all of it, the gate controller's replay included."""
         n_admit = int(admit.sum())
-        if n_admit == 0:
-            return 0
-        self.tinstant("admit", cls=kind, n=n_admit)
-        t0 = self.clock.now_s()
-        return self._finish_class(admit, per_frame, t0, n_admit,
-                                  dt_override_s=dt_share_s)
+        with self.tspan("commit", cls=kind, n=n_admit):
+            gate = self.gates[kind]
+            if gate is not None and active.any():
+                gate.commit_decision(active, admit)
+            for lane in np.nonzero(active & ~admit)[0]:
+                self.lanes[lane].gated += 1
+            if n_admit == 0:
+                return 0
+            self.tinstant("admit", cls=kind, n=n_admit)
+            t0 = self.clock.now_s()
+            return self._finish_class(admit, per_frame, t0, n_admit,
+                                      dt_override_s=dt_share_s)
 
     def _ingest_pallas(self, batch: jax.Array, gate: Optional[MotionGate],
                        active: np.ndarray):

@@ -159,6 +159,144 @@ def test_parallel_tick_single_fused_dispatch_per_tick():
 
 
 # ---------------------------------------------------------------------------
+# spans of the fused tick (obs.tracing) on a wall-clocked fleet
+# ---------------------------------------------------------------------------
+
+RES = 64
+
+
+def _traced_fleet(tracer=None, clock=None, frames=6):
+    """Two wall-clocked replicas, two vehicles with ``frames`` distinct
+    frame pairs each queued; returns the gateway."""
+    import jax
+    from repro.streams import FleetGateway, VisionServeEngine
+    replicas = [VisionServeEngine(f"r{i}", slots=2, frame_res=RES,
+                                  input_res=RES // 2, use_gate=True,
+                                  rng=jax.random.key(i),
+                                  clock=clock() if clock else None)
+                for i in range(2)]
+    gw = FleetGateway(replicas, parallel=True, tracer=tracer)
+    rng = np.random.default_rng(0)
+    for v in range(2):
+        gw.join(f"v{v}")
+        for _ in range(frames):
+            gw.push(f"v{v}",
+                    rng.random((RES, RES, 3)).astype(np.float32),
+                    rng.random((RES, RES, 3)).astype(np.float32))
+    return gw
+
+
+def _drain(gw) -> int:
+    ticks = 0
+    while any(r.has_work() for r in gw.live_replicas()):
+        gw.tick()
+        ticks += 1
+    return ticks
+
+
+def _lane(tr, lane):
+    tids = {e["args"]["name"]: e["tid"] for e in tr.events
+            if e["ph"] == "M"}
+    return [e for e in tr.spans() if e["tid"] == tids[lane]]
+
+
+def _end(e):
+    return e["ts"] + e["dur"]
+
+
+EPS_US = 0.002          # span times are rounded to the ns
+
+
+def _inside(child, parent):
+    return (parent["ts"] - EPS_US <= child["ts"]
+            and _end(child) <= _end(parent) + EPS_US)
+
+
+def test_fused_tick_spans_nest_in_order_and_cover_the_tick():
+    from repro.obs import SpanTracer
+    tr = SpanTracer()
+    gw = _traced_fleet(tr)
+    ticks = _drain(gw)
+    fleet = _lane(tr, "fleet")
+    ft = [e for e in fleet if e["name"] == "fleet.tick"]
+    fd = [e for e in fleet if e["name"] == "fused_dispatch"]
+    assert len(ft) == ticks and len(fd) == gw._fleet.dispatches > 0
+    # fused_dispatch is the very interval last_dispatch_s measured
+    assert fd[-1]["dur"] == pytest.approx(
+        gw._fleet.last_dispatch_s * 1e6, abs=EPS_US)
+    assert tr.spans("tick") == []     # fleet.tick replaces R copies
+    order = ("fleet.gather", "fleet.call", "fleet.wait")
+    after = ("fleet.readback", "fleet.commit", "fleet.end")
+    for tick in ft:
+        inner = {e["name"]: e for e in fleet
+                 if e is not tick and _inside(e, tick)}
+        assert "fleet.end" in inner
+        if "fused_dispatch" not in inner:
+            continue
+        d = inner["fused_dispatch"]
+        assert all(_inside(inner[n], d) for n in order)
+        seq = [inner[n] for n in order] + [d] + [inner[n] for n in after]
+        for a, b in zip(seq, seq[1:]):
+            if b is d:
+                continue
+            assert _end(a) <= b["ts"] + EPS_US, (a["name"], b["name"])
+        assert inner["fleet.call"]["args"]["bytes"] == 2 * 2 * RES * RES * 3 * 4
+        covered = sum(inner[n]["dur"] for n in ("fused_dispatch",) + after)
+        assert covered >= 0.8 * tick["dur"]
+        for r in ("r0", "r1"):
+            lane = [e for e in _lane(tr, r) if _inside(e, tick)]
+            assert {"rebalance", "stage", "commit"} <= {
+                e["name"] for e in lane}
+
+
+def test_sample_every_thins_the_fleet_spans():
+    from repro.obs import SpanTracer
+    tr = SpanTracer(sample_every=3)
+    ticks = _drain(_traced_fleet(tr))
+    ft = tr.spans("fleet.tick")
+    assert [e["args"]["tick"] for e in ft] == list(range(0, ticks, 3))
+    assert all(any(_inside(e, t) for t in ft)
+               for e in _lane(tr, "fleet"))
+    assert len(tr.spans("fused_dispatch")) <= len(ft) < ticks
+
+
+def test_an_unsampled_or_detached_tick_reads_no_more_clock_than_untraced():
+    """The null path: ticks that record nothing read the engine clocks
+    exactly as often as a fleet that never had a tracer."""
+    from repro.core.clock import WallClock
+    from repro.obs import NULL_TRACER, SpanTracer
+
+    reads = []
+
+    class Counting(WallClock):
+        def now_s(self):
+            reads[-1] += 1
+            return super().now_s()
+
+    def per_tick(gw, n):
+        out = []
+        for _ in range(n):
+            reads.append(0)
+            gw.tick()
+            out.append(reads.pop())
+        return out
+
+    reads.append(0)
+    plain = per_tick(_traced_fleet(clock=Counting), 6)
+    tr = SpanTracer(sample_every=1000)
+    reads.append(0)
+    gw = _traced_fleet(tr, clock=Counting)
+    traced = per_tick(gw, 3)
+    gw.attach_obs(tracer=NULL_TRACER)
+    assert gw.tracer is NULL_TRACER and gw._fleet.tracer is NULL_TRACER
+    assert all(r.tracer is NULL_TRACER for r in gw.replicas)
+    traced += per_tick(gw, 3)
+    assert traced[0] > plain[0]                # tick 0 is sampled
+    assert traced[1:] == plain[1:]
+    assert len(tr.spans("fleet.tick")) == 1
+
+
+# ---------------------------------------------------------------------------
 # slow: full-length library + shard_map on a forced multi-device host mesh
 # ---------------------------------------------------------------------------
 

@@ -261,6 +261,74 @@ def test_tracer_max_events_drops_not_grows():
     assert tr.dropped == 10 - (5 - 1)           # 1 slot went to metadata
 
 
+def test_clock_anchors_map_perf_counter_to_unix_time():
+    """Attached to a wall-clocked fleet, the tracer exports an anchor
+    from attach and one from export; through them a perf_counter reading
+    lands within the reads' gaps of time.time_ns()."""
+    import time
+    from repro.obs.tracing import to_unix_ns
+    tr = SpanTracer()
+    tr.attach(wall_clock=True)
+    try:
+        time.sleep(0.01)
+        p0 = time.perf_counter_ns()
+        unix = time.time_ns()
+        p1 = time.perf_counter_ns()
+        anchors = tr.to_chrome()["otherData"]["clock_anchors"]
+    finally:
+        tr.detach()
+    assert len(anchors) == 2
+    assert anchors[0]["perf_ns"] < p0 < p1 < anchors[1]["perf_ns"]
+    assert all(0 <= a["gap_ns"] < 1_000_000 for a in anchors)
+    got = to_unix_ns(anchors, (p0 + p1) / 2 * 1e-9)
+    assert abs(got - unix) <= (p1 - p0) + max(a["gap_ns"] for a in anchors)
+    json.dumps(tr.to_chrome())
+
+
+def test_to_unix_ns_interpolates_between_first_and_last_anchor():
+    from repro.obs.tracing import to_unix_ns
+    anchors = [{"perf_ns": 1_000, "unix_ns": 5_000_000, "gap_ns": 10},
+               {"perf_ns": 2_000, "unix_ns": 9_999_999, "gap_ns": 10},
+               {"perf_ns": 1_001_000, "unix_ns": 6_000_010, "gap_ns": 10}]
+    # the outer pair only: 1e6 + 10 unix ns over 1e6 perf ns
+    assert to_unix_ns(anchors, 1_000e-9) == 5_000_000
+    assert to_unix_ns(anchors, 501_000e-9) == 5_000_000 + 500_005
+    assert to_unix_ns(anchors[:1], 3_000e-9) == 5_002_000
+
+
+def test_virtual_clocked_attach_exports_no_anchor_and_no_gc_lane():
+    import gc
+    tr = SpanTracer()
+    tr.attach(wall_clock=False)
+    assert tr.to_chrome()["otherData"]["clock_anchors"] == []
+    gc.collect()
+    assert tr.spans("gc") == []
+    tr.detach()
+
+
+def test_gc_lane_records_collections_while_attached_and_sampled():
+    import gc
+    tr = SpanTracer(sample_every=2)
+    tr.attach(wall_clock=True)
+    hooks = len(gc.callbacks)
+    tr.attach(wall_clock=True)                 # a second fleet: one hook
+    assert len(gc.callbacks) == hooks
+    gc.collect()
+    (ev,) = tr.spans("gc")
+    assert ev["args"]["generation"] == 2 and ev["dur"] >= 0
+    tr.for_tick(1)                             # an unsampled tick
+    gc.collect()
+    assert len(tr.spans("gc")) == 1
+    tr.for_tick(2)
+    tr.detach()
+    gc.collect()                               # one fleet still holds it
+    assert len(tr.spans("gc")) == 2
+    tr.detach()
+    assert len(gc.callbacks) == hooks - 1
+    gc.collect()
+    assert len(tr.spans("gc")) == 2
+
+
 def test_tracer_dump(tmp_path):
     tr = SpanTracer()
     tr.complete("tick", "r0", 1.0, 0.5, tick=7)

@@ -58,7 +58,34 @@ def test_golden_digest_identical_with_obs_on_parallel():
     res, _, tracer = _obs_run("golden_churn", parallel=True)
     assert not res.violations, "\n".join(map(str, res.violations))
     assert res.digest == _golden_digest()
-    assert len(tracer.spans("fused_dispatch")) > 0
+    for name in ("fleet.tick", "fused_dispatch", "fleet.gather",
+                 "fleet.call", "fleet.wait", "fleet.readback",
+                 "fleet.commit", "fleet.end", "rebalance", "stage",
+                 "commit"):
+        assert len(tracer.spans(name)) > 0, name
+
+
+def test_parallel_obs_export_is_deterministic_per_seed():
+    """Under virtual clocks every fleet span, fused_dispatch included,
+    takes its start and length from the lead replica's clock: two
+    same-seed runs export the same trace, with no clock anchor."""
+    exports = [json.dumps(_obs_run("golden_churn", parallel=True)[2]
+                          .to_chrome(), sort_keys=True)
+               for _ in range(2)]
+    assert exports[0] == exports[1]
+    assert json.loads(exports[0])["otherData"]["clock_anchors"] == []
+
+
+def test_sampled_tracer_keeps_digest_on_the_parallel_path():
+    """sample_every thins the fleet lane as it thins the replicas'."""
+    from repro.simulate import get_scenario, run_scenario
+    sampled = SpanTracer(sample_every=8)
+    res = run_scenario(get_scenario("golden_churn"), parallel=True,
+                       metrics=MetricsRegistry(), tracer=sampled)
+    assert res.digest == _golden_digest()
+    ticks = sampled.spans("fleet.tick")
+    assert ticks and all(e["args"]["tick"] % 8 == 0 for e in ticks)
+    assert 0 < len(sampled.spans("fused_dispatch")) <= len(ticks)
 
 
 def test_sampled_tracer_keeps_digest_and_drops_events():
@@ -73,6 +100,24 @@ def test_sampled_tracer_keeps_digest_and_drops_events():
                        metrics=MetricsRegistry(), tracer=sampled)
     assert res.digest == _golden_digest()
     assert 0 < len(sampled.spans("tick")) < len(full.spans("tick"))
+
+
+@pytest.mark.parametrize("name,span", [("traffic_spike", "tiers"),
+                                       ("partitioned_reconnect",
+                                        "events.pump")])
+def test_gateway_lane_records_the_director_and_the_pump(name, span):
+    """The gateway lane holds a span for each tier-director round and
+    each event-pump round (the scenario's ticks and its drain), and
+    observes only."""
+    from repro.simulate import get_scenario, run_scenario
+    scenario = get_scenario(name, ticks=30)
+    plain = run_scenario(scenario)
+    obs, _, tracer = _obs_run(name, ticks=30)
+    assert obs.digest == plain.digest
+    assert len(tracer.spans(span)) >= 30
+    lanes = {e["tid"]: e["args"]["name"] for e in tracer.events
+             if e["ph"] == "M"}
+    assert {lanes[e["tid"]] for e in tracer.spans(span)} == {"gateway"}
 
 
 def test_ledger_sketch_parity_on_golden_scenario():
