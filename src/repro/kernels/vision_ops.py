@@ -55,11 +55,14 @@ Host/XLA split assumption
 The host owns stream lifecycle, backlog deques and the admission *decision*
 (adaptive thresholds are tiny scalar state, host-side in ``MotionGate``); the
 device owns everything O(pixels): normalize, resample, score, scatter.  The
-engine stages frames into a pinned host buffer and ships one (S, H, W, C)
-array per tick; only the (S,) score vector crosses back before the admit
-mask returns for ``scatter_admit``.  Frames arrive at engine frame
-resolution — decode/crop from camera-native resolution happens upstream —
-and a whole frame is one block, so the frame resolution is bounded by VMEM.
+fleet tick (``streams.fleet_step``) stages frames into a pinned host buffer
+already in the kernels' plane layout and ships one (R, S, H, W*C) array per
+tick, which the chip takes row-major with no host transpose (a serial
+engine ships its own (S, H, W, C) buffer); only the (S,) score vector
+crosses back before the admit mask returns for ``scatter_admit``.  Frames
+arrive at engine frame resolution — decode/crop from camera-native
+resolution happens upstream — and a whole frame is one block, so the frame
+resolution is bounded by VMEM.
 
 ``interpret=None`` auto-selects interpreter mode off-TPU, so the CPU parity
 suite (``tests/test_vision_kernels.py``) executes the kernel bodies

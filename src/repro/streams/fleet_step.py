@@ -9,7 +9,7 @@ of dividing it, the opposite of the paper's parallel-devices scaling story
 leading ``replica`` axis —
 
     batch pools   (R, slots, res, res, 3)   per model class
-    stage frames  (R, slots, H, W, 3)       pinned host buffers, one upload
+    stage frames  (R, slots, H, W*3)        pinned host buffers, one upload
     gate refs     (R, slots, g, g, 3)       + thresh/has_ref (R, slots)
     lane masks    (R, slots) bool           liveness is masked, not reshaped
     model params  pytrees stacked to (R, ...)
@@ -33,6 +33,13 @@ replicas in one jit containing one mapped computation per **tier group**:
   * ``mode="vmap"``: the same stacked state through ``jax.vmap`` of the
     same body — the single-device / CPU / interpret fallback and the only
     mode for mixed-tier fleets.
+
+Frames stage in the ingest kernels' ``(H, W*3)`` plane layout: row-major
+host data that tiles the chip's ``(8, 128)`` layout as it stands (384 px
+rows are 1152 = 9 x 128 lanes), so the upload needs no host transpose and
+the kernels read the argument with no device relayout.  The mapped body
+views each replica's planes as ``(slots, H, W, 3)`` frames, a row-major
+reshape the compiler folds into the kernels' own plane view.
 
 Inside the mapped body the existing kernels are reused unchanged:
 ``kernels.vision_ops.ingest_frame`` / ``scatter_admit`` on the Pallas
@@ -156,6 +163,8 @@ def _build_fused(mode: str, mesh, members: Tuple[Tuple[int, ...], ...],
         geometry."""
 
         def one_class(forward, batch, stage, refs, thr, href, act):
+            S, H, WC = stage.shape                # staged (H, W*3) planes
+            stage = stage.reshape(S, H, WC // 3, 3)
             if use_pallas:
                 if use_gate:
                     model, small, scores = vision_ops.ingest_frame(
@@ -362,19 +371,20 @@ class FleetStep:
                 f"of {jax.device_count()} devices", stacklevel=3)
             self.mode = "vmap"
         self.mesh = replica_mesh(R) if self.mode == "shard_map" else None
-        # one pinned staging buffer per tier group; each engine's _stage
-        # is a view of its group row, so the host never copies frames
-        # again and the fused call uploads each group's staging in one
-        # piece (frames always arrive at the uniform frame_res, f32)
+        # one pinned staging buffer per tier group, in the kernels'
+        # (H, W*3) planes; each engine's _stage is a (slots, H, W, 3) view
+        # of its group row, so the host never copies frames again and the
+        # fused call uploads each group's staging in one piece (frames
+        # always arrive at the uniform frame_res, f32)
+        res = ref.frame_res
         self._stage_groups: List[np.ndarray] = []
         for g, mem in enumerate(self._members):
-            buf = np.zeros((len(mem), self.slots, ref.frame_res,
-                            ref.frame_res, 3), np.float32)
+            buf = np.zeros((len(mem), self.slots, res, res * 3), np.float32)
             self._stage_groups.append(buf)
             for j, i in enumerate(mem):
                 r = self.replicas[i]
                 r.enable_host_staging()
-                r._stage = buf[j]
+                r._stage = buf[j].reshape(self.slots, res, res, 3)
         # engines never retrain: stack the per-group model params once
         self._dp = [_stack_trees([self.replicas[i].dp for i in mem])
                     for mem in self._members]

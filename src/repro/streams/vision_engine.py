@@ -222,7 +222,8 @@ class VisionServeEngine(EngineCore):
     def enable_host_staging(self) -> None:
         """Stage popped frames into the pinned host buffer (the Pallas
         path's layout) on the jnp path too: the fleet-parallel tick ships
-        one (slots, H, W, 3) buffer per tick and scatters it on device,
+        its replicas' staged frames as one buffer per tick (each engine's
+        ``_stage`` a view of it) and scatters them on device,
         replacing the per-frame ``_load_frame`` dispatch loop with
         bit-identical batch contents."""
         if not hasattr(self, "_stage"):

@@ -158,6 +158,43 @@ def test_parallel_tick_single_fused_dispatch_per_tick():
     assert gw._fleet.dispatches - before == ticks
 
 
+@pytest.mark.parametrize("input_res", [(16, 16, 16), (16, 8, 16)],
+                         ids=["uniform", "mixed_tier"])
+def test_engine_stage_is_a_view_of_its_group_planes(input_res):
+    """Each tier group stages into one (members, slots, H, W*3) buffer;
+    an engine's ``_stage`` is a (slots, H, W, 3) view of its row, so a
+    frame staged through ``stage_class`` lands at ``[lane, h, w*3 + c]``
+    of the buffer the fused call uploads."""
+    import jax
+    from repro.streams import VisionServeEngine
+    from repro.streams.fleet_step import FleetStep
+    from repro.streams.vision_engine import OUTER
+    res, slots = 32, 2
+    replicas = [VisionServeEngine(f"r{i}", slots=slots, frame_res=res,
+                                  input_res=ires, use_gate=True,
+                                  rng=jax.random.key(i))
+                for i, ires in enumerate(input_res)]
+    fleet = FleetStep(replicas, warm=False)
+    assert len(fleet._stage_groups) == len(set(input_res))
+    rng = np.random.default_rng(0)
+    for buf, mem in zip(fleet._stage_groups, fleet._members):
+        assert buf.shape == (len(mem), slots, res, res * 3)
+        assert buf.dtype == np.float32 and buf.flags.c_contiguous
+        for j, i in enumerate(mem):
+            r = replicas[i]
+            assert r._stage.shape == (slots, res, res, 3)
+            assert np.shares_memory(r._stage, buf[j])
+            r.open_stream(f"cam{i}", OUTER)
+            frame = rng.random((res, res, 3)).astype(np.float32)
+            assert r.push(f"cam{i}", frame)
+            active = r.stage_class(OUTER)
+            lane = int(np.flatnonzero(active)[0])
+            h, w, c = 5, 7, 2
+            assert buf[j, lane, h, w * 3 + c] == frame[h, w, c]
+            np.testing.assert_array_equal(buf[j, lane],
+                                          frame.reshape(res, res * 3))
+
+
 # ---------------------------------------------------------------------------
 # spans of the fused tick (obs.tracing) on a wall-clocked fleet
 # ---------------------------------------------------------------------------
