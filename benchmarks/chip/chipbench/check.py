@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from chipbench.drive import INNER, KINDS, OUTER
+from chipbench.spec import model_res
 
 MASK_ROW = {OUTER: (0, 2), INNER: (1, 3)}        # (admit, flags) rows
 
@@ -61,7 +62,8 @@ def compare(samples: list, pools: dict, cfg: dict, ref, params,
                 b = bef[kind]
                 model, gate, score = (np.asarray(x) for x in ref.ingest(
                     jnp.asarray(src), jnp.asarray(b["refs"]),
-                    model_res=cfg["input_res"], gate_res=cfg["gate_res"],
+                    model_res=model_res(cfg, kind),
+                    gate_res=cfg["gate_res"],
                     block=cfg["gate_block"], control=False))
                 want = act & ((score > b["thresh"]) | ~b["has_ref"])
                 ties = act & b["has_ref"] & (np.abs(score - b["thresh"])
@@ -70,7 +72,7 @@ def compare(samples: list, pools: dict, cfg: dict, ref, params,
                     c_model, c_gate, c_score = (np.asarray(x) for x in
                                                 ref.ingest(
                         jnp.asarray(src), jnp.asarray(b["refs"]),
-                        model_res=cfg["input_res"],
+                        model_res=model_res(cfg, kind),
                         gate_res=cfg["gate_res"], block=cfg["gate_block"],
                         control=True))
                     got = act & ((c_score > b["thresh"]) | ~b["has_ref"])

@@ -61,7 +61,8 @@ class Record:
     """What one phase of the run measured."""
     ticks: List[tuple] = field(default_factory=list)   # (tick s, dispatch s)
     resolved: int = 0          # frames resolved by the phase's ticks
-    active: List[int] = field(default_factory=list)    # per tick: staged
+    # frames each camera staged in the phase's ticks (those they resolved)
+    active: dict = field(default_factory=lambda: {OUTER: 0, INNER: 0})
     admitted: dict = field(default_factory=lambda: {OUTER: 0, INNER: 0})
     turnaround_s: List[float] = field(default_factory=list)
     captured_s: List[float] = field(default_factory=list)  # of the same
@@ -179,6 +180,7 @@ class Driver:
                     rec.captured_s.append(t_cap)
                 resolved.append((s, idx, k < d_proc))
             rec.admitted[s.kind] += d_proc
+            rec.active[s.kind] += d_proc + d_gate
         rec.resolved += len(resolved)
 
     def _span(self, name: str, t0: float) -> None:
@@ -196,7 +198,6 @@ class Driver:
         rec.ticks.append((t1 - t0, dispatch))
         resolved: list = []
         self._account(t1, rec, resolved)
-        rec.active.append(len(resolved))
         if cap is not None:
             self.sampler.after(cap, resolved, self.fleet.last_masks)
         if self.spans is not None:

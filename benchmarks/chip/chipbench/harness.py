@@ -88,6 +88,16 @@ def end_to_end(name: str, rec: drive.Record, setup_s: float):
     raise KeyError(f"no end-to-end metric {name!r}")
 
 
+def run_view(cfg: dict, ref, rec: drive.Record, red, peak: dict,
+             chips: int) -> layers.RunView:
+    """What the per-layer readers read of a run: the window, its trace,
+    and each camera's operations a frame as the configuration's reference
+    counts them."""
+    return layers.RunView(cfg=cfg, rec=rec, red=red, peak=peak, chips=chips,
+                          model_flops={k: ref.model_flops(cfg, k)
+                                       for k in drive.KINDS})
+
+
 def run(cell: spec.Cell, seed: int, seconds: float, traced: bool, *,
         t_start: float, require_tpu: bool = True,
         keep_trace: Optional[Path] = None, samples: int = 32,
@@ -118,7 +128,8 @@ def run(cell: spec.Cell, seed: int, seconds: float, traced: bool, *,
              for kind, w in ((OUTER, w_outer), (INNER, w_inner))}
     t_pool = time.perf_counter()
     ref = spec.load_module(cell.reference)
-    calib = {k: ref.downscale(jnp.asarray(p[:CALIB_FRAMES]), cfg["input_res"])
+    calib = {k: ref.downscale(jnp.asarray(p[:CALIB_FRAMES]),
+                              spec.model_res(cfg, k))
              for k, p in pools.items()}
     params = jax.block_until_ready(ref.make_params(cfg, w_params, calib))
     t_params = time.perf_counter()
@@ -217,8 +228,7 @@ def run(cell: spec.Cell, seed: int, seconds: float, traced: bool, *,
 
     metrics = {}
     if traced:
-        view = layers.RunView(cfg=cfg, rec=rec, red=red, peak=peak,
-                              chips=len(devs))
+        view = run_view(cfg, ref, rec, red, peak, len(devs))
         for m in cell.per_layer:
             v = spec.load_reader(cell.readers[m["name"]])(view)
             if v is not None:
@@ -234,8 +244,10 @@ def run(cell: spec.Cell, seed: int, seconds: float, traced: bool, *,
     del gw, drv, sampler, replicas
     gc.collect()
     limits = cell.limits
+    t_check = time.perf_counter()
     numbers = check.compare(kept, pools, cfg, ref, params)
     ok, rows = check.verdict(numbers, limits)
+    log(f"comparison s: {time.perf_counter() - t_check}")
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": jax.device_count(), "memory_peak_bytes": int(mem)}
     result = {"correct": bool(ok), "attempted": rec.attempted,

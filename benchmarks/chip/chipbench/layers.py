@@ -8,12 +8,13 @@ want of data.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Optional
+from typing import Dict, Optional
 
 import numpy as np
 
 from chipbench import work
-from chipbench.drive import INNER, OUTER, Record
+from chipbench.drive import KINDS, Record
+from chipbench.spec import model_res
 from chipbench.trace import Reduced
 
 
@@ -25,6 +26,7 @@ class RunView:
     red: Optional[Reduced]        # its trace, reduced (--trace 1)
     peak: dict                    # peaks.peaks(device_kind)
     chips: int
+    model_flops: Dict[str, int]   # a frame of each camera, its reference's
 
 
 def turnaround_ms(run: RunView, q: float) -> Optional[float]:
@@ -56,22 +58,19 @@ def ingest_roofline(run: RunView, kernel: str) -> Optional[float]:
     t = run.red.kernel_s(kernel)
     if t <= 0:
         return None
-    c, n = run.cfg, sum(run.rec.active)
-    nbytes = work.ingest_bytes(n, c["frame_res"], c["input_res"],
-                               c["gate_res"])
+    c, active = run.cfg, run.rec.active
+    n = sum(active.values())
+    nbytes = sum(work.ingest_bytes(active[k], c["frame_res"],
+                                   model_res(c, k), c["gate_res"])
+                 for k in KINDS)
     least, _ = work.least_time_s(nbytes, work.ingest_flops(n, c["gate_res"]),
                                  run.peak)
     return 100.0 * least / t
 
 
-def useful_model_flops(run: RunView) -> float:
-    c = run.cfg
-    det = work.model_flops(c["detector"], c["input_res"],
-                           c["detector"]["num_anchors"]
-                           * (c["detector"]["num_classes"] + 5))
-    pose = work.model_flops(c["pose"], c["input_res"],
-                            c["pose"]["num_keypoints"])
-    return (run.rec.admitted[OUTER] * det + run.rec.admitted[INNER] * pose)
+def useful_model_flops(run: RunView) -> int:
+    """Admitted frames of each camera through its model."""
+    return sum(run.rec.admitted[k] * run.model_flops[k] for k in KINDS)
 
 
 def mfu(run: RunView) -> Optional[float]:

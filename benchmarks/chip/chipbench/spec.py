@@ -7,6 +7,22 @@ mix.  The configuration's entry gives its file; the mix is
 ``metrics/<base>.py``; a configuration's plain reference is
 ``references/<reference>.py``.  A later cell, configuration, mix or metric
 is added by adding files and entries, never by editing this code.
+
+A configuration file states the fleet (replicas, lanes, mode, frame and
+gate sizes), each camera's model in a section of its own (``detector``
+for the outer camera, ``pose`` for the inner one), whose every key is a
+field of the program's ``VisionConfig`` (``chipbench/fleet.py`` applies
+them field by field and refuses a key the program lacks), its
+``limits`` of ``correct``, and the name of its plain reference.  A
+camera's model resolution is its section's ``input_res``, else the
+file's (``model_res``).  The reference provides, by those names:
+
+* ``make_params(cfg, seed, calib)``: the weights, from the seed;
+* ``downscale(frames, res)``: the resample the calibration rows take;
+* ``ingest(frames, refs, ...)``: the ingest and gate of a camera's lanes;
+* ``margins(params, rows, cfg, kind)``: each frame's flag margins;
+* ``model_flops(cfg, kind)``: the operations of one frame through the
+  camera's model, which ``mfu`` counts.
 """
 from __future__ import annotations
 
@@ -18,6 +34,13 @@ from typing import Callable, Dict, List, Optional
 
 BENCH_DIR = Path(__file__).resolve().parents[1]       # benchmarks/chip
 ROOT = BENCH_DIR.parents[1]                           # the checkout
+SECTION = {"outer": "detector", "inner": "pose"}      # camera -> its model
+
+
+def model_res(cfg: dict, kind: str) -> int:
+    """The model resolution of camera ``kind``: its model section's
+    ``input_res`` where the configuration gives one, else the file's."""
+    return int(cfg[SECTION[kind]].get("input_res", cfg["input_res"]))
 
 
 @dataclass

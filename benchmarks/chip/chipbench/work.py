@@ -8,10 +8,11 @@ later change to how the work is done reads against the same yardstick:
   Resampling by nearest neighbour is a gather: no operations.  The
   motion score is a difference, an absolute value and a sum per gate
   value, and a mean per gate pixel.
-* models: multiply-adds times two of one frame through the backbone and
-  the head, from the configuration's widths (the same count as
-  ``repro.models.vision.model_flops``, copied here so that the yardstick
-  stays with the benchmark).
+
+A model's operations a frame are its configuration's: each plain
+reference counts them (``model_flops(cfg, kind)`` in
+``references/<reference>.py``), so that a configuration that brings
+another model brings its count with it.
 """
 from __future__ import annotations
 
@@ -26,24 +27,6 @@ def ingest_bytes(frames: int, frame_res: int, model_res: int,
 
 def ingest_flops(frames: int, gate_res: int, channels: int = 3) -> int:
     return frames * (3 * channels + 1) * gate_res ** 2
-
-
-def backbone_flops(channels, input_res: int) -> int:
-    hw = input_res // 2
-    total = 2 * 9 * 3 * channels[0] * hw * hw                 # stem
-    for i in range(1, len(channels)):
-        if i <= 3:
-            hw //= 2
-        total += 2 * 9 * channels[i - 1] * hw * hw            # depthwise
-        total += 2 * channels[i - 1] * channels[i] * hw * hw  # pointwise
-    return total
-
-
-def model_flops(model: dict, input_res: int, head_out: int) -> int:
-    """One frame through a backbone and its 3x3 head."""
-    hw = input_res // 16
-    head = 2 * 9 * model["channels"][-1] * head_out * hw * hw
-    return backbone_flops(model["channels"], input_res) + head
 
 
 def least_time_s(nbytes: float, flops: float, peak: dict):

@@ -15,7 +15,9 @@ nothing of the program:
   stride 2, then depthwise 3x3 + pointwise 1x1 blocks, stride 2 in the
   first three, ReLU6) with a 3x3 head: SSD-style anchors for the outer
   camera's hazard detector, keypoint heat maps for the inner camera's
-  distraction detector, and the paper's flag rules on top.
+  distraction detector, and the paper's flag rules on top;
+* work: the operations of one frame through each camera's model
+  (``model_flops``), which the benchmark's ``mfu`` counts.
 
 Flags are booleans, so the reference returns each flag's *margin*: a
 number whose sign is the flag and whose size is how far the evidence
@@ -45,6 +47,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from chipbench.spec import SECTION, model_res
 
 HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -165,6 +169,38 @@ def make_params(cfg: dict, seed: int, calib: dict) -> dict:
     sizes = {k: cfg[k] for k in ("detector", "pose", "init", "precision")}
     return _make(jax.random.key(seed), calib["outer"], calib["inner"],
                  json.dumps(sizes, sort_keys=True))
+
+
+# ---------------------------------------------------------------------------
+# work: multiply-adds times two of one frame through a model
+# ---------------------------------------------------------------------------
+
+
+def backbone_flops(channels, input_res: int) -> int:
+    hw = input_res // 2
+    total = 2 * 9 * 3 * channels[0] * hw * hw                 # stem
+    for i in range(1, len(channels)):
+        if i <= 3:
+            hw //= 2
+        total += 2 * 9 * channels[i - 1] * hw * hw            # depthwise
+        total += 2 * channels[i - 1] * channels[i] * hw * hw  # pointwise
+    return total
+
+
+def network_flops(model: dict, input_res: int, head_out: int) -> int:
+    """One frame through a backbone and its 3x3 head."""
+    hw = input_res // 16
+    head = 2 * 9 * model["channels"][-1] * head_out * hw * hw
+    return backbone_flops(model["channels"], input_res) + head
+
+
+def model_flops(cfg: dict, kind: str) -> int:
+    """Operations of one frame through camera ``kind``'s model (``outer``
+    the detector, ``inner`` the pose model) at its model resolution."""
+    model = cfg[SECTION[kind]]
+    out = (detector_out(model) if kind == "outer"
+           else model["num_keypoints"])
+    return network_flops(model, model_res(cfg, kind), out)
 
 
 # ---------------------------------------------------------------------------

@@ -12,7 +12,8 @@ sys.path[:0] = [str(BENCH.parents[1] / "src"), str(BENCH)]
 
 from chipbench import harness, spec  # noqa: E402
 
-SHRINK = dict(replicas=2, slots=4, frame_res=128, input_res=64, gate_res=16,
+# the sizes, not the fleet's geometry: a cell keeps its replicas and mode
+SHRINK = dict(slots=4, frame_res=128, input_res=64, gate_res=16,
               gate_block=4)
 
 

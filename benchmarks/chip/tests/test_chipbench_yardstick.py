@@ -16,6 +16,8 @@ sys.path[:0] = [str(BENCH.parents[1] / "src"), str(BENCH)]
 
 from chipbench import drive, harness, layers, peaks, spec, work  # noqa: E402
 
+REF = spec.load_module(BENCH / "references" / "eda_vision.py")
+
 
 class Clock:
     def __init__(self) -> None:
@@ -223,12 +225,13 @@ def _view(rec, red=None):
                         "num_classes": 10, "num_anchors": 4},
            "pose": {"channels": [16, 32, 64, 128, 256],
                     "num_keypoints": 17}}
-    return layers.RunView(cfg=cfg, rec=rec, red=red,
-                          peak=peaks.peaks("TPU v5 lite"), chips=1)
+    return harness.run_view(cfg, REF, rec, red, peaks.peaks("TPU v5 lite"),
+                            chips=1)
 
 
 def test_readers_return_nothing_without_a_trace():
-    rec = drive.Record(ticks=[(0.01, 0.005)], active=[4],
+    rec = drive.Record(ticks=[(0.01, 0.005)],
+                       active={"outer": 2, "inner": 2},
                        admitted={"outer": 2, "inner": 1})
     view = _view(rec)
     assert layers.device_idle_share(view) is None
@@ -250,6 +253,8 @@ def test_work_functions_match_the_shapes_the_program_reports():
 
 @pytest.mark.parametrize("res", [64, 192, 256])
 def test_model_flops_copy_matches_the_program(res):
+    """The reference's count is ``repro.models.vision.model_flops``, for
+    the program's own models and at the configuration's widths."""
     import dataclasses
     from repro.configs.eda_vision import detector_config, pose_config
     from repro.models import vision as V
@@ -259,10 +264,39 @@ def test_model_flops_copy_matches_the_program(res):
                                num_classes=90, num_anchors=6)
     wide_pose = dataclasses.replace(pose_config(res),
                                     channels=coco.channels)
-    for vc, out in ((detector_config(res), 4 * 15), (pose_config(res), 17),
-                    (coco, 6 * 95), (wide_pose, 17)):
-        model = {"channels": list(vc.channels)}
-        assert work.model_flops(model, res, out) == V.model_flops(vc)
+    for det, pose in ((detector_config(res), pose_config(res)),
+                      (coco, wide_pose)):
+        models = {"input_res": res,
+                  "detector": {"channels": list(det.channels),
+                               "num_classes": det.num_classes,
+                               "num_anchors": det.num_anchors},
+                  "pose": {"channels": list(pose.channels),
+                           "num_keypoints": pose.num_keypoints}}
+        assert REF.model_flops(models, "outer") == V.model_flops(det)
+        assert REF.model_flops(models, "inner") == V.model_flops(pose)
+
+
+@pytest.mark.parametrize("cell", ["eda192-motion-sat", "eda192-motion-25fps",
+                                  "eda192x4-motion-sat"])
+def test_eda_fleet_192_reads_its_golden_work_counts(cell):
+    """Operations of a detector and of a pose frame, and bytes of one
+    frame's ingest, as the cells have read them since they were set."""
+    cfg = spec.resolve(cell).config
+    ref = spec.load_module(spec.resolve(cell).reference)
+    assert ref.model_flops(cfg, "outer") == 2_463_547_392
+    assert ref.model_flops(cfg, "inner") == 995_770_368
+    for kind in drive.KINDS:
+        assert work.ingest_bytes(1, cfg["frame_res"], spec.model_res(cfg, kind),
+                                 cfg["gate_res"]) == 2_236_420
+    # the window's ingest: each camera's frames at its resolution
+    rec = drive.Record(active={"outer": 3, "inner": 2})
+    red = SimpleNamespace(kernel_s=lambda part: 1.0)
+    peak = peaks.peaks("TPU v5 lite")
+    view = harness.run_view(cfg, ref, rec, red, peak, chips=1)
+    least, bound = work.least_time_s(5 * 2_236_420,
+                                     work.ingest_flops(5, 32), peak)
+    assert bound == "memory"
+    assert layers.ingest_roofline(view, "x") == 100.0 * least
 
 
 def test_least_time_names_its_bound():
