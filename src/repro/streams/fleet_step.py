@@ -17,9 +17,11 @@ leading ``replica`` axis —
 — and runs ingest → gate-score → admit-threshold → model forward for *all*
 replicas in one jit containing one mapped computation per **tier group**:
 
-  * replicas are grouped by model geometry — ``(dc, pc, input_res,
-    batch dtype)``, i.e. by :class:`~repro.streams.tiers.TierSpec` in a
-    tiered fleet.  A uniform fleet is one group and compiles to exactly
+  * replicas are grouped by model geometry — ``(dc, pc, model planes,
+    batch dtype)``, the planes each camera's own resolution
+    (``VisionServeEngine.model_res``), i.e. by
+    :class:`~repro.streams.tiers.TierSpec` in a tiered fleet.  A uniform
+    fleet is one group and compiles to exactly
     the pre-tier program; a mixed-tier fleet gets one vmapped body per
     group, all inside the *same* jit, so a whole heterogeneous fleet tick
     is still a single device dispatch (the 1-dispatch-per-tick contract
@@ -147,35 +149,36 @@ def _build_fused(mode: str, mesh, members: Tuple[Tuple[int, ...], ...],
 
     Keyed on everything the closure captures — mode/mesh, the tier-group
     layout (``members`` = replica indices per group, ``group_keys`` =
-    each group's (dc, pc, input_res, dtype)), gate geometry, kernel
-    path — so repeated ``FleetStep`` construction (bench repeats, test
-    sweeps, gateway rebuilds) reuses one compiled XLA program instead of
-    recompiling per instance.  Model params are call arguments, never
+    each group's (dc, pc, (outer plane, inner plane), dtype)), gate
+    geometry, kernel path — so repeated ``FleetStep`` construction (bench
+    repeats, test sweeps, gateway rebuilds) reuses one compiled XLA
+    program instead of recompiling per instance.  Model params are call arguments, never
     captured."""
     if use_pallas:
         from repro.kernels import vision_ops
     R = sum(len(m) for m in members)
 
-    def make_single(dc, pc, input_res):
+    def make_single(dc, pc, planes):
         """Per-group single-replica tick body (both classes, no replica
         axis) — mirrors the device half of
         ``VisionServeEngine._step_class`` exactly, at this group's model
-        geometry."""
+        geometry: each class ingests to its own model plane."""
 
-        def one_class(forward, batch, stage, refs, thr, href, act):
+        def one_class(forward, model_res, batch, stage, refs, thr, href,
+                      act):
             S, H, WC = stage.shape                # staged (H, W*3) planes
             stage = stage.reshape(S, H, WC // 3, 3)
             if use_pallas:
                 if use_gate:
                     model, small, scores = vision_ops.ingest_frame(
-                        stage, refs, model_res=input_res, gate_res=gate_res,
+                        stage, refs, model_res=model_res, gate_res=gate_res,
                         block=block, interpret=interpret)
                     admit = act & ((scores > thr) | ~href)
                     batch, refs = vision_ops.scatter_admit(
                         batch, model, refs, small, admit,
                         interpret=interpret)
                 else:
-                    model = vision_ops.downscale(stage, input_res,
+                    model = vision_ops.downscale(stage, model_res,
                                                  interpret=interpret)
                     admit = act
                     batch, _ = vision_ops.scatter_admit(
@@ -207,9 +210,10 @@ def _build_fused(mode: str, mesh, members: Tuple[Tuple[int, ...], ...],
                 return distracted
 
             out: Dict[str, jax.Array] = {}
-            for kind, forward in ((OUTER, fwd_outer), (INNER, fwd_inner)):
+            for kind, forward, res in ((OUTER, fwd_outer, planes[0]),
+                                       (INNER, fwd_inner, planes[1])):
                 admit, flags, batch, refs = one_class(
-                    forward, ops[f"batch_{kind}"], ops["stage"],
+                    forward, res, ops[f"batch_{kind}"], ops["stage"],
                     ops[f"refs_{kind}"], ops[f"thr_{kind}"],
                     ops[f"href_{kind}"], ops[f"act_{kind}"])
                 out[f"admit_{kind}"] = admit
@@ -221,8 +225,8 @@ def _build_fused(mode: str, mesh, members: Tuple[Tuple[int, ...], ...],
 
         return single
 
-    singles = [make_single(dc, pc, ires)
-               for (dc, pc, ires, _dtype) in group_keys]
+    singles = [make_single(dc, pc, planes)
+               for (dc, pc, planes, _dtype) in group_keys]
 
     if mode == "shard_map":
         assert len(singles) == 1, "shard_map requires one tier group"
@@ -319,8 +323,9 @@ class FleetStep:
         ref = self.replicas[0]
         for r in self.replicas:
             # fleet-wide uniform: slot width, source frame geometry, and
-            # kernel path.  Model geometry (dc/pc/input_res/batch dtype)
-            # may differ per replica — those split into tier groups below.
+            # kernel path.  Model geometry (dc/pc/model planes/batch
+            # dtype) may differ per replica — those split into tier groups
+            # below.
             for attr in ("slots", "frame_res", "use_pallas"):
                 if getattr(r, attr) != getattr(ref, attr):
                     raise ValueError(
@@ -349,8 +354,8 @@ class FleetStep:
         # tier groups: replicas sharing one model geometry map together.
         # Grouping is by first appearance, so a uniform fleet is exactly
         # one group in replica order (the pre-tier layout).
-        sigs = [(r.dc, r.pc, r.input_res, str(r.batches[OUTER].dtype))
-                for r in self.replicas]
+        sigs = [(r.dc, r.pc, (r.model_res(OUTER), r.model_res(INNER)),
+                 str(r.batches[OUTER].dtype)) for r in self.replicas]
         self._group_keys: List[tuple] = []
         self._members: List[List[int]] = []
         for i, sig in enumerate(sigs):

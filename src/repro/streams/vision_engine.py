@@ -52,7 +52,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.config import EDAConfig
-from repro.configs.eda_vision import detector_config, pose_config
+from repro.configs.eda_vision import (VisionConfig, detector_config,
+                                      pose_config)
 from repro.core.clock import FRAME, Clock
 from repro.events.envelope import DEADLINE_MISS, DISTRACTION, HAZARD
 from repro.core.engine_core import INNER, OUTER, EngineCore, LanePool
@@ -155,22 +156,20 @@ class VisionServeEngine(EngineCore):
         self.max_pending = max_pending
         self.quantum = quantum
 
+        # each camera's batch pool follows its model (``dc``, ``pc``): the
+        # fused-ingest path holds model-plane frames (ingest_frame emits
+        # them); the legacy path stages at frame resolution and lets the
+        # model jit downscale internally
+        self._batch_dtype = (self.tier.jnp_dtype() if self.tier is not None
+                             else jnp.float32)
+        self._models: Dict[str, VisionConfig] = {}
+        self.batches: Dict[str, jax.Array] = {}
         rng = rng if rng is not None else jax.random.key(0)
         r1, r2 = jax.random.split(rng)
         self.dc = detector_config(input_res)
         self.pc = pose_config(input_res)
         self.dp = V.init_detector(self.dc, r1)
         self.pp = V.init_pose(self.pc, r2)
-
-        # fused-ingest path: the batch pool holds model-resolution frames
-        # (ingest_frame emits them); legacy path stages at frame resolution
-        # and lets the model jit downscale internally
-        res = input_res if use_pallas else frame_res
-        shape = (slots, res, res, 3)
-        batch_dtype = (self.tier.jnp_dtype() if self.tier is not None
-                       else jnp.float32)
-        self.batches = {OUTER: jnp.zeros(shape, batch_dtype),
-                        INNER: jnp.zeros(shape, batch_dtype)}
         if use_pallas:
             from repro.kernels import vision_ops
             self._vk = vision_ops
@@ -218,6 +217,49 @@ class VisionServeEngine(EngineCore):
         self.frame_cost_ms = self.unit_cost_ms
         self.results: Dict[str, Deque[bool]] = {}
         self.frames_processed = 0
+
+    # ------------------------------------------------------------------
+    # the two cameras' models and their model planes
+    # ------------------------------------------------------------------
+    @property
+    def dc(self) -> VisionConfig:
+        """The outer camera's model (the hazard detector)."""
+        return self._models[OUTER]
+
+    @dc.setter
+    def dc(self, cfg: VisionConfig) -> None:
+        self._install(OUTER, cfg)
+
+    @property
+    def pc(self) -> VisionConfig:
+        """The inner camera's model (the distraction pose model)."""
+        return self._models[INNER]
+
+    @pc.setter
+    def pc(self, cfg: VisionConfig) -> None:
+        self._install(INNER, cfg)
+
+    def model_res(self, kind: str) -> int:
+        """The resolution of camera ``kind``'s model plane, which its
+        ingest writes and its batch pool holds.  A model that names its
+        own head (``ssd``, ``centernet``) brings its published input
+        resolution; the program's grid-head models, which a tier scales,
+        take the engine's ``input_res``, and on a tiered engine every
+        model does: a tier pins one resolution for both cameras."""
+        cfg = self._models[kind]
+        if self.tier is None and cfg.head != "grid":
+            return cfg.input_res
+        return self.input_res
+
+    def _install(self, kind: str, cfg: VisionConfig) -> None:
+        """Install camera ``kind``'s model and shape its batch pool to the
+        model's plane: a new, empty pool where the rows change."""
+        self._models[kind] = cfg
+        res = self.model_res(kind) if self.use_pallas else self.frame_res
+        shape = (self.slots, res, res, 3)
+        pool = self.batches.get(kind)
+        if pool is None or pool.shape != shape:
+            self.batches[kind] = jnp.zeros(shape, self._batch_dtype)
 
     def enable_host_staging(self) -> None:
         """Stage popped frames into the pinned host buffer (the Pallas
@@ -543,7 +585,8 @@ class VisionServeEngine(EngineCore):
         gate = self.gates[kind]
         if self.use_pallas:
             with self.tspan("ingest", cls=kind):
-                batch, admit = self._ingest_pallas(batch, gate, active)
+                batch, admit = self._ingest_pallas(
+                    batch, gate, active, self.model_res(kind))
             self.batches[kind] = batch
         else:
             with self.tspan("gate", cls=kind):
@@ -628,14 +671,15 @@ class VisionServeEngine(EngineCore):
                                       dt_override_s=dt_share_s)
 
     def _ingest_pallas(self, batch: jax.Array, gate: Optional[MotionGate],
-                       active: np.ndarray):
+                       active: np.ndarray, res: int):
         """Fused ingest tick: one kernel pass scores + downscales the staged
-        frames, the host thresholds, one masked scatter commits admitted
-        rows into the batch and the gate references."""
+        frames to the ``res`` model plane, the host thresholds, one masked
+        scatter commits admitted rows into the batch and the gate
+        references."""
         staged = jnp.asarray(self._stage)
         if gate is not None:
             model, small, scores = self._vk.ingest_frame(
-                staged, gate.refs, model_res=self.input_res,
+                staged, gate.refs, model_res=res,
                 gate_res=gate.gate_res, block=gate.block,
                 interpret=self._interpret)
             admit = gate.decide(np.asarray(scores), active)
@@ -643,7 +687,7 @@ class VisionServeEngine(EngineCore):
                 batch, model, gate.refs, small, jnp.asarray(admit),
                 interpret=self._interpret)
         else:
-            model = self._vk.downscale(staged, self.input_res,
+            model = self._vk.downscale(staged, res,
                                        interpret=self._interpret)
             admit = active
             batch, _ = self._vk.scatter_admit(

@@ -23,6 +23,7 @@ deterministic fallback (``tests/_hypothesis_stub.py``).  Properties:
 from dataclasses import dataclass
 
 import numpy as np
+import pytest
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -41,6 +42,15 @@ def _fleet(n_replicas, slots, overcommit):
                                  input_res=32, fps=10, use_gate=False)
                for i in range(n_replicas)]
     return engines, FleetGateway(engines, overcommit=overcommit)
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _warm_engines():
+    """One fleet built before the timed examples: a process's first engine
+    compiles its default models' initialisers (seconds), which
+    hypothesis's per-example deadline would otherwise charge to whichever
+    example comes first."""
+    _fleet(2, 1, 1.0)
 
 
 @settings(max_examples=12)

@@ -18,8 +18,12 @@ imports this file.
 """
 from __future__ import annotations
 
+import dataclasses
+import difflib
 import functools
+import gzip
 import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -27,7 +31,9 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
 
-from repro.configs.eda_vision import detector_config, pose_config
+from repro.configs.eda_vision import (MOBILENET_V1, detector_config,
+                                      movenet_lightning_config, pose_config,
+                                      ssd_mobilenet_v1_config)
 from repro.kernels import vision_ops
 from repro.models import vision as V
 from repro.streams.fleet_step import _build_fused
@@ -94,12 +100,13 @@ def test_vision_kernel_compiles_for_v5e(name, one_chip):
     assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
 
 
-def _fleet_operands(R, sharded, replica_state):
-    """Abstract operands of the fused tick for R replicas at the smoke
-    geometry: stacked params from ``jax.eval_shape``, everything else
-    from its shape.  ``replica_state(shape)`` builds a per-replica state
+def _fleet_operands(R, sharded, replica_state, models=None, slots=S):
+    """Abstract operands of the fused tick for R replicas, by default at
+    the smoke geometry: stacked params from ``jax.eval_shape``, everything
+    else from its shape, each camera's batch pool at its model's input
+    resolution.  ``replica_state(shape)`` builds a per-replica state
     operand (a tuple of arrays)."""
-    dc, pc = detector_config(MODEL), pose_config(MODEL)
+    dc, pc = models or (detector_config(MODEL), pose_config(MODEL))
     key = jax.random.key(0)
 
     def stacked(tree):
@@ -110,45 +117,58 @@ def _fleet_operands(R, sharded, replica_state):
                functools.partial(V.init_detector, dc), key)),
            "pp": stacked(jax.eval_shape(
                functools.partial(V.init_pose, pc), key)),
-           "stage": _sds((R, S, FRAME, FRAME * 3), sharded)}
-    for kind in ("outer", "inner"):
-        ops[f"thr_{kind}"] = _sds((R, S), sharded)
-        ops[f"href_{kind}"] = _sds((R, S), sharded, BOOL)
-        ops[f"act_{kind}"] = _sds((R, S), sharded, BOOL)
-        ops[f"batch_{kind}"] = replica_state((S, MODEL, MODEL, 3))
-        ops[f"refs_{kind}"] = replica_state((S, GATE, GATE, 3))
+           "stage": _sds((R, slots, FRAME, FRAME * 3), sharded)}
+    for kind, vc in (("outer", dc), ("inner", pc)):
+        ops[f"thr_{kind}"] = _sds((R, slots), sharded)
+        ops[f"href_{kind}"] = _sds((R, slots), sharded, BOOL)
+        ops[f"act_{kind}"] = _sds((R, slots), sharded, BOOL)
+        ops[f"batch_{kind}"] = replica_state(
+            (slots, vc.input_res, vc.input_res, 3))
+        ops[f"refs_{kind}"] = replica_state((slots, GATE, GATE, 3))
     return (dc, pc), ops
+
+
+def _one_chip_tick(one_chip, R, models=None, slots=S):
+    """The fused tick of R replicas under ``vmap`` on one chip,
+    compiled."""
+    (dc, pc), ops = _fleet_operands(
+        R, one_chip, lambda shape: tuple(_sds(shape, one_chip)
+                                         for _ in range(R)), models, slots)
+    fused = _build_fused("vmap", None, (tuple(range(R)),),
+                         ((dc, pc, (dc.input_res, pc.input_res),
+                           "float32"),), True, True, GATE, BLOCK, False)
+    return fused.lower([ops]).compile()
+
+
+def _mesh_tick(topo, R, models=None, slots=S):
+    """The fused tick of R replicas in ``shard_map`` over the 2x2 replica
+    mesh, R / 4 a chip, compiled."""
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), ("replica",))
+    sharded = NamedSharding(mesh, PartitionSpec("replica"))
+    k = R // 4
+    # each state operand is k flat arrays whose per-chip shard is one of
+    # that chip's replicas' (slots, ...) arrays
+    (dc, pc), ops = _fleet_operands(
+        R, sharded, lambda shape: tuple(
+            _sds((4 * shape[0],) + shape[1:], sharded) for _ in range(k)),
+        models, slots)
+    fused = _build_fused("shard_map", mesh, (tuple(range(R)),),
+                         ((dc, pc, (dc.input_res, pc.input_res),
+                           "float32"),), True, True, GATE, BLOCK, False)
+    return fused.lower(ops).compile()
 
 
 @pytest.fixture(scope="module")
 def fused_one_chip(one_chip):
     """The fused tick for two replicas on one chip, compiled."""
-    R = 2
-    (dc, pc), ops = _fleet_operands(
-        R, one_chip, lambda shape: tuple(_sds(shape, one_chip)
-                                         for _ in range(R)))
-    fused = _build_fused("vmap", None, (tuple(range(R)),),
-                         ((dc, pc, MODEL, "float32"),), True, True, GATE,
-                         BLOCK, False)
-    return fused.lower([ops]).compile()
+    return _one_chip_tick(one_chip, 2)
 
 
 @pytest.fixture(scope="module")
 def fused_mesh(topo):
     """The fused tick over the 2x2 replica mesh, one replica a chip,
     compiled."""
-    R = 4
-    mesh = Mesh(np.asarray(topo.devices).reshape(R), ("replica",))
-    sharded = NamedSharding(mesh, PartitionSpec("replica"))
-    # one replica per chip: each state operand is one flat array whose
-    # per-chip shard is that replica's (slots, ...) array
-    (dc, pc), ops = _fleet_operands(
-        R, sharded, lambda shape: (_sds((R * shape[0],) + shape[1:],
-                                        sharded),))
-    fused = _build_fused("shard_map", mesh, (tuple(range(R)),),
-                         ((dc, pc, MODEL, "float32"),), True, True, GATE,
-                         BLOCK, False)
-    return fused.lower(ops).compile()
+    return _mesh_tick(topo, 4)
 
 
 def test_fused_fleet_tick_compiles_for_one_v5e_chip(fused_one_chip):
@@ -177,15 +197,23 @@ def _unplaced(layout: str) -> str:
     return re.sub(r"S\(\d+\)", "", layout)
 
 
-@pytest.mark.parametrize("program", ["fused_one_chip", "fused_mesh"])
-def test_fused_tick_reads_staged_planes_without_a_relayout(program, request):
+@pytest.fixture
+def program(request):
+    """The compiled tick a test names, compiled at set-up: a compile takes
+    seconds and belongs to no test's timed call."""
+    return request.getfixturevalue(request.param)
+
+
+@pytest.mark.parametrize("program", ["fused_one_chip", "fused_mesh",
+                                     "fused_pair"], indirect=True)
+def test_fused_tick_reads_staged_planes_without_a_relayout(program):
     """The stage parameter is row-major (W*3 minor, then H), so the host's
     planes upload as they are, and no copy in the tick writes the stage's
     elements in another layout: the kernels read the parameter itself.  A
     copy into another memory space at the parameter's own layout (the
     compiler's prefetch into VMEM, which it chooses by size) moves the
     bytes without reordering them and is not a relayout."""
-    text = request.getfixturevalue(program).as_text()
+    text = program.as_text()
     params = re.findall(r"^\s*%\S+ = f32\[([\d,]+)\]\{([^}]*)\} "
                         r"parameter\(.*op_name=\"[^\"]*stage", text, re.M)
     assert len(params) == 1, params
@@ -201,3 +229,97 @@ def test_fused_tick_reads_staged_planes_without_a_relayout(program, request):
                                           outs):
             if _elements(out) == n:
                 assert _unplaced(out_layout) == _unplaced(layout), outs
+
+
+# ---------------------------------------------------------------------------
+# the benchmark's configurations: eda-fleet-192 as it compiled before the
+# published pair's models came in, and the pair's own tick
+# ---------------------------------------------------------------------------
+
+
+def canonical(text: str) -> str:
+    """Compiled HLO text without what only names or places its parts: the
+    source tables, op metadata, instruction names (renumbered by first
+    use) and the Mosaic kernels' serialized bodies, which carry source
+    locations (the kernels themselves are compiled above)."""
+    out, skip = [], False
+    for ln in text.split("\n"):
+        if ln in ("FileNames", "FunctionNames", "FileLocations",
+                  "StackFrames"):
+            skip = True
+            continue
+        if skip and (ln.startswith(("%", "ENTRY")) or not ln):
+            skip = False
+        if not skip:
+            out.append(ln)
+    text = re.sub(r",? ?metadata=\{[^}]*\}", "", "\n".join(out))
+    text = re.sub(r'"body":"[^"]*"', '"body":""', text)
+    names: dict = {}
+    return re.sub(r"%[\w.\-]+",
+                  lambda m: names.setdefault(m.group(0), f"%v{len(names)}"),
+                  text)
+
+
+def _eda_fleet_192():
+    """eda-fleet-192's models: MobileNetV1 1.0 widths, COCO's 90 classes
+    and 6 anchors a cell, at 192."""
+    return (dataclasses.replace(detector_config(MODEL), channels=MOBILENET_V1,
+                                num_classes=90, num_anchors=6),
+            dataclasses.replace(pose_config(MODEL), channels=MOBILENET_V1))
+
+
+# ``canonical`` of eda-fleet-192's compiled fused ticks, 16 lanes a
+# replica: two replicas on one chip, and eight over the 2x2 mesh, two a
+# chip.  Recorded from the program before the published pair's models
+# came in, with JAX 0.9.0 and its TPU compiler; a new compiler rewrites
+# them (``golden/eda-fleet-192.<program>.hlo.gz``) once its programs are
+# checked to be the same.
+GOLDEN = Path(__file__).resolve().parent / "golden"
+EDA_FLEET_192 = ("mesh", "one_chip")
+
+
+@pytest.fixture(scope="module", params=EDA_FLEET_192)
+def eda_fleet_192_tick(request, topo, one_chip):
+    """(program, compiled) of eda-fleet-192's fused tick."""
+    if request.param == "one_chip":
+        return request.param, _one_chip_tick(one_chip, 2, _eda_fleet_192(),
+                                             slots=16)
+    return request.param, _mesh_tick(topo, 8, _eda_fleet_192(), slots=16)
+
+
+def test_eda_fleet_192_compiles_as_before_the_published_pair(
+        eda_fleet_192_tick):
+    program, compiled = eda_fleet_192_tick
+    got = canonical(compiled.as_text())
+    with gzip.open(GOLDEN / f"eda-fleet-192.{program}.hlo.gz", "rt") as f:
+        want = f.read()
+    if got != want:
+        diff = difflib.unified_diff(want.split("\n"), got.split("\n"),
+                                    "before", "now", n=1, lineterm="")
+        pytest.fail("eda-fleet-192's compiled tick changed:\n"
+                    + "\n".join(list(diff)[:80]))
+
+
+@pytest.fixture(scope="module")
+def fused_pair(one_chip):
+    """eda-fleet-ssd300-movenet's fused tick: two replicas of 16 lanes,
+    SSD-MobileNetV1 at 300 and MoveNet Lightning at 192, compiled."""
+    return _one_chip_tick(one_chip, 2, (ssd_mobilenet_v1_config(),
+                                        movenet_lightning_config()),
+                          slots=16)
+
+
+def test_the_published_pairs_tick_compiles_with_a_plane_per_camera(
+        fused_pair):
+    """The 384 px frames resample to a 300x900 plane for SSD and a
+    192x576 one for MoveNet, each in an ingest kernel of its own, and the
+    tick fits a v5e's 16 GB many times over."""
+    text = fused_pair.as_text()
+    # the ingest kernel's three outputs: the model plane, the gate plane
+    # and the scores, each with the replica and lane axes ahead
+    planes = re.findall(r"^\s*%\S+ = \(f32\[[\d,]*?(\d+),(\d+)\]\S*, "
+                        r"f32\[[\d,]+\]\S*, f32\[[\d,]+\]\S*\) custom-call\(",
+                        text, re.M)
+    assert sorted(set(planes)) == [("192", "576"), ("300", "900")]
+    stats = fused_pair.memory_analysis()
+    assert stats.temp_size_in_bytes + stats.argument_size_in_bytes < 2**31
