@@ -68,21 +68,34 @@ Under virtual clocks (``repro.simulate``) the parallel tick is
 bit-identical to the serial tick: same admit decisions, same ledger
 records, same golden-trace digests (pinned by ``tests/test_fleet_step``).
 
+Staging copies: the replicas' ``stage_class`` trims and pops, and hands
+the popped frames back; the tick then copies them all into the group
+planes in one pass (:meth:`FleetStep._copy_staged`), in destination-row
+order, split into contiguous runs over a small thread pool (NumPy's copy
+releases the interpreter lock).  How many threads is worked out from what
+the tick observes (:func:`stage_workers`): ``min(8, usable CPUs - 1,
+frames)``, the caller alone where that is 1.  The bytes, the planes and
+the compiled tick are those of an inline copy.
+
 Spans (``obs.tracing``, on the ``fleet`` lane and on the lead live
 replica's clock and sampled tick): ``fleet.tick`` around the whole tick;
+``stage`` around the copy pass (args ``frames``, ``bytes``, ``workers``);
 ``fused_dispatch`` over exactly the interval ``last_dispatch_s`` times,
 with ``fleet.gather`` / ``fleet.call`` (the jit call returning, ``bytes``
 staged) / ``fleet.wait`` (``block_until_ready``) inside it; then
 ``fleet.readback`` (the masks to the host), ``fleet.commit`` (state
 unstacked, every ``commit_class``) and ``fleet.end`` (``end_tick`` and
 the scheduler's feedback).  The replicas' own lanes hold ``rebalance``,
-``stage`` and ``commit``.
+``stage`` (trim and pop) and ``commit``.
 """
 from __future__ import annotations
 
 import functools
+import operator
+import os
 import time
 import warnings
+from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -99,6 +112,32 @@ from repro.streams.vision_engine import (INNER, OUTER, VisionServeEngine,
                                          _scatter_stage_impl)
 
 MODES = ("shard_map", "vmap")
+
+STAGE_MAX_WORKERS = 8
+
+
+def stage_workers(frames: int, cpus: int) -> int:
+    """Threads that copy one tick's ``frames`` staged frames, given
+    ``cpus`` usable CPUs: ``min(8, cpus - 1, frames)``, one CPU left to the
+    caller and the runtime; 1 means the caller copies inline."""
+    return max(1, min(STAGE_MAX_WORKERS, cpus - 1, frames))
+
+
+def _usable_cpus() -> int:
+    return len(os.sched_getaffinity(0))
+
+
+@functools.lru_cache(maxsize=1)
+def _stage_pool() -> ThreadPoolExecutor:
+    """The process's one staging-copy pool, built on first use and shared
+    by every fleet, so copy threads never number more than
+    :data:`STAGE_MAX_WORKERS`."""
+    return ThreadPoolExecutor(STAGE_MAX_WORKERS, thread_name_prefix="stage")
+
+
+def _copy_run(run) -> None:
+    for dst, frame in run:
+        dst[...] = frame
 
 
 def resolve_mode(n_replicas: int, mode: Optional[str] = None) -> str:
@@ -406,6 +445,7 @@ class FleetStep:
                           for mem in self._members]
         self._mem_idx = [np.asarray(mem, int) for mem in self._members]
         self._staged_bytes = sum(b.nbytes for b in self._stage_groups)
+        self._frame_bytes = self._stage_groups[0][0, 0].nbytes
         self.tracer = NULL_TRACER      # the gateway's, set by attach_obs
         if self.mesh is not None:
             self._place_on_mesh()
@@ -545,11 +585,14 @@ class FleetStep:
         t0s = {r.name: r.begin_tick() for r in live}
         act = {OUTER: np.zeros((R, self.slots), bool),
                INNER: np.zeros((R, self.slots), bool)}
+        staged: List[list] = [[] for _ in self.replicas]
         for i, r in enumerate(self.replicas):
             if r.name in gw.dead:
                 continue
             for kind in (OUTER, INNER):
-                act[kind][i] = r.stage_class(kind)
+                act[kind][i] = r.stage_class(kind, staged[i])
+        if any(staged):
+            self._copy_staged(staged, tr, clk)
 
         per_done = {r.name: 0 for r in live}
         wall_share_s = {r.name: 0.0 for r in live}
@@ -612,6 +655,27 @@ class FleetStep:
             # reorder pool allocation between serial and parallel ticks.
             done += gw._tick_tokens()
         return done
+
+    def _copy_staged(self, staged: List[list], tr, clk) -> None:
+        """Copy the frames the replicas popped (``staged[i]``, replica
+        ``i``'s ``(lane, frame)`` pairs) into the group planes: in
+        destination-row order, inline or as one contiguous run per thread
+        (:func:`stage_workers`), back when every run is in."""
+        copies = [(self.replicas[i]._stage[lane], frame)
+                  for mem in self._members for i in mem
+                  for lane, frame in sorted(staged[i],
+                                            key=operator.itemgetter(0))]
+        n = len(copies)
+        nbytes = n * self._frame_bytes
+        workers = stage_workers(n, _usable_cpus())
+        with tr.span(clk, "stage", tid="fleet", frames=n, bytes=nbytes,
+                     workers=workers):
+            if workers == 1:
+                _copy_run(copies)
+            else:
+                cuts = [n * w // workers for w in range(workers + 1)]
+                runs = [copies[a:b] for a, b in zip(cuts, cuts[1:])]
+                list(_stage_pool().map(_copy_run, runs))  # raises run errors
 
     def _commit(self, gw, out, masks, act, wall, per_done,
                 wall_share_s) -> None:

@@ -539,12 +539,18 @@ class VisionServeEngine(EngineCore):
         self.end_tick(t0, done)
         return done
 
-    def stage_class(self, kind: str) -> np.ndarray:
+    def stage_class(self, kind: str,
+                    copies: Optional[list] = None) -> np.ndarray:
         """Deadline-trim and pop one frame per bound ``kind`` stream into
         the staging layout (batch rows on the jnp path, the pinned host
         buffer on the Pallas path).  Returns the (slots,) active mask.
         The device work on the staged frames happens in :meth:`_step_class`
         serially, or in one fused fleet dispatch (``streams.fleet_step``).
+
+        Given ``copies`` (host staging only), nothing is copied: each
+        popped ``(lane, frame)`` pair is appended, ``lane`` being the
+        frame's row of ``_stage``, for the caller to copy (the fused tick
+        copies a whole tick's frames in one pass).
         """
         with self.tspan("stage", cls=kind):
             batch = self.batches[kind]
@@ -561,7 +567,9 @@ class VisionServeEngine(EngineCore):
                                               st.pending[0])
                 frame = st.pending.popleft()
                 st.served_since_bind += 1  # gated frames consume quantum too
-                if self.use_pallas or self._host_staging:
+                if copies is not None:
+                    copies.append((lane, frame))
+                elif self.use_pallas or self._host_staging:
                     self._stage[lane] = frame
                 else:
                     batch = _load_frame(batch,

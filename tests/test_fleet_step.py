@@ -272,13 +272,16 @@ def test_fused_tick_spans_nest_in_order_and_cover_the_tick():
             continue
         d = inner["fused_dispatch"]
         assert all(_inside(inner[n], d) for n in order)
-        seq = [inner[n] for n in order] + [d] + [inner[n] for n in after]
+        # the fleet's staging copies run before the dispatch it times
+        seq = ([inner["stage"]] + [inner[n] for n in order] + [d]
+               + [inner[n] for n in after])
         for a, b in zip(seq, seq[1:]):
             if b is d:
                 continue
             assert _end(a) <= b["ts"] + EPS_US, (a["name"], b["name"])
         assert inner["fleet.call"]["args"]["bytes"] == 2 * 2 * RES * RES * 3 * 4
-        covered = sum(inner[n]["dur"] for n in ("fused_dispatch",) + after)
+        covered = sum(inner[n]["dur"]
+                      for n in ("stage", "fused_dispatch") + after)
         assert covered >= 0.8 * tick["dur"]
         for r in ("r0", "r1"):
             lane = [e for e in _lane(tr, r) if _inside(e, tick)]
@@ -331,6 +334,116 @@ def test_an_unsampled_or_detached_tick_reads_no_more_clock_than_untraced():
     assert traced[0] > plain[0]                # tick 0 is sampled
     assert traced[1:] == plain[1:]
     assert len(tr.spans("fleet.tick")) == 1
+
+
+# ---------------------------------------------------------------------------
+# the tick's staging copies (FleetStep._copy_staged)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("workers", [1, 2, 4])
+def test_copy_pass_matches_inline_copies(workers, monkeypatch):
+    """The fused tick's copy pass, inline or split over threads, writes
+    each popped frame to the bytes an inline ``stage_class`` copy writes,
+    over two tier groups, and leaves idle lanes as they were."""
+    import jax
+    from repro.obs import SpanTracer
+    from repro.streams import VisionServeEngine
+    from repro.streams import fleet_step
+    from repro.streams.vision_engine import INNER, OUTER
+    monkeypatch.setattr(fleet_step, "_usable_cpus", lambda: workers + 1)
+    res, slots = 32, 4
+    replicas = [VisionServeEngine(f"r{i}", slots=slots, frame_res=res,
+                                  input_res=ires, use_gate=True,
+                                  rng=jax.random.key(i))
+                for i, ires in enumerate((16, 8, 16))]
+    fleet = fleet_step.FleetStep(replicas, warm=False)
+    assert len(fleet._stage_groups) == 2
+    for buf in fleet._stage_groups:
+        buf[...] = -1.0                       # what an idle lane keeps
+    expected = [buf.copy() for buf in fleet._stage_groups]
+    where = {i: (g, j) for g, mem in enumerate(fleet._members)
+             for j, i in enumerate(mem)}
+    rng = np.random.default_rng(workers)
+    staged = [[] for _ in replicas]
+    for i, r in enumerate(replicas):
+        # three of the four lanes bound on r0 and r2, one on r1
+        kinds = (OUTER, INNER, OUTER) if i != 1 else (INNER,)
+        for n, kind in enumerate(kinds):
+            r.open_stream(f"cam{i}.{n}", kind)
+            assert r.push(f"cam{i}.{n}",
+                          rng.random((res, res, 3)).astype(np.float32))
+        frames = {lane: st.pending[0] for lane, st in enumerate(r.lanes)
+                  if st is not None}
+        for kind in (OUTER, INNER):
+            active = r.stage_class(kind, staged[i])
+            assert active.sum() == kinds.count(kind)
+        assert sorted(lane for lane, _ in staged[i]) == sorted(frames)
+        g, j = where[i]
+        for lane, frame in frames.items():
+            expected[g][j, lane] = frame.reshape(res, res * 3)
+    assert all((buf == -1.0).all() for buf in fleet._stage_groups)
+    tr = SpanTracer()
+    fleet._copy_staged(staged, tr, replicas[0].clock)
+    for got, want in zip(fleet._stage_groups, expected):
+        assert got.tobytes() == want.tobytes()
+    (stage,) = tr.spans("stage")
+    assert stage["args"] == {"frames": 7, "bytes": 7 * res * res * 3 * 4,
+                             "workers": workers}
+
+
+@pytest.mark.parametrize("frames", [32, 128])
+def test_stage_worker_count_rule(frames):
+    """2-8 threads at a tick of 32 or 128 frames, never more than the
+    usable CPUs less one, the frames, or 8; the caller alone (1) for one
+    frame or two CPUs or fewer."""
+    from repro.streams.fleet_step import (STAGE_MAX_WORKERS, _usable_cpus,
+                                          stage_workers)
+    assert STAGE_MAX_WORKERS == 8
+    assert stage_workers(1, 64) == 1
+    for cpus in (1, 2):
+        assert stage_workers(frames, cpus) == 1
+    for cpus in (3, 4, 8, 9, 13, 30, 240):
+        w = stage_workers(frames, cpus)
+        assert 2 <= w <= 8 and w <= cpus - 1
+        assert w == min(8, cpus - 1)
+    assert stage_workers(3, 64) == 3
+    cpus = _usable_cpus()
+    assert 1 <= stage_workers(frames, cpus) <= min(cpus, 8)
+
+
+def test_wall_clocked_tick_copies_on_workers(monkeypatch):
+    """A wall-clocked fused tick copies on threads where the host has the
+    CPUs: the fleet lane's ``stage`` span says how many, and the masks,
+    batch pools and staging planes are those of the same tick copied
+    inline."""
+    from repro.obs import SpanTracer
+    from repro.streams import fleet_step
+    from repro.streams.vision_engine import INNER, OUTER
+
+    def one_tick(cpus):
+        monkeypatch.setattr(fleet_step, "_usable_cpus", lambda: cpus)
+        tr = SpanTracer()
+        gw = _traced_fleet(tr, frames=1)
+        gw.tick()
+        fleet = gw._fleet
+        (stage,) = [e for e in _lane(tr, "fleet") if e["name"] == "stage"]
+        return (tr, stage, fleet.last_masks.copy(),
+                [np.asarray(r.batches[k]) for r in gw.replicas
+                 for k in (OUTER, INNER)],
+                [buf.copy() for buf in fleet._stage_groups])
+
+    _, inline, *want = one_tick(2)
+    assert inline["args"]["workers"] == 1
+    tr, stage, *got = one_tick(8)
+    assert stage["args"] == {"frames": 4, "bytes": 4 * RES * RES * 3 * 4,
+                             "workers": 4}
+    fl = _lane(tr, "fleet")
+    (tick,) = [e for e in fl if e["name"] == "fleet.tick"]
+    (gather,) = [e for e in fl if e["name"] == "fleet.gather"]
+    assert _inside(stage, tick) and _end(stage) <= gather["ts"] + EPS_US
+    np.testing.assert_array_equal(got[0], want[0])
+    for a, b in zip(got[1] + got[2], want[1] + want[2]):
+        assert a.tobytes() == b.tobytes()
 
 
 # ---------------------------------------------------------------------------
